@@ -100,9 +100,26 @@ print("LEARNER_RESULT " + json.dumps(
 """
 
 
+def _require_cpu() -> None:
+    """The children force host devices, so this section runs on the CPU
+    backend only. On an accelerator this process already holds the chip
+    (``benchmarks.common`` imports JAX), and a child would fail or hang
+    trying to open it."""
+    import jax
+    backend = jax.default_backend()
+    if backend != "cpu":
+        raise RuntimeError(
+            f"benchmarks/learner_scaling.py runs each D in a child process "
+            f"on forced host devices; this process already holds the "
+            f"{backend!r} backend, which a child cannot open. Run the "
+            f"section with JAX_PLATFORMS=cpu; its rows are CPU timings, "
+            f"not chip numbers.")
+
+
 def _child(d: int, iterations: int, budget: int, env_batch: int,
            fsdp: bool = False, overlap: bool = False) -> dict:
-    env = dict(os.environ,
+    _require_cpu()
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                PYTHONPATH=os.pathsep.join(
                    p for p in (os.path.join(REPO, "src"),
                                os.environ.get("PYTHONPATH", "")) if p))

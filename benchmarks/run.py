@@ -227,6 +227,8 @@ def main(argv=None) -> None:
         ap.error(f"unknown sections {unknown}; choose from {list(table)}")
     # fail before benchmarks run, not after minutes of measurement
     check_dirty_overwrite(args.out_dir, _git_rev(), args.force)
+    from repro.launch import compile_cache
+    compile_cache.enable()
 
     print("name,us_per_call,derived")
     for name in names:

@@ -159,7 +159,9 @@ class FusedRunner(BackendCloseMixin):
         return (None if self.state.plane_state is None
                 else self.state.plane_state[0])
 
-    def _loop_for(self, chunk: int) -> Callable:
+    def loop_for(self, chunk: int) -> Callable:
+        """The jitted, donating function that runs ``chunk`` fused
+        iterations: ``loop_for(c).lower(runner.state)`` is the program."""
         if chunk not in self._loops:
             self._loops[chunk] = make_fused_train_loop(
                 self.env, self.learn, self.horizon, chunk,
@@ -268,7 +270,7 @@ class FusedRunner(BackendCloseMixin):
         done = 0
         while done < iterations:
             c = min(self.chunk or iterations, iterations - done)
-            loop = self._loop_for(c)
+            loop = self.loop_for(c)
             t0 = time.perf_counter()
             self.state, metrics = loop(self.state)
             jax.block_until_ready(self.state.params)

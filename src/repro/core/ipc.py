@@ -466,8 +466,9 @@ def _worker_env():
     inherit the environment at spawn; the parent's own, already-
     initialized client is unaffected):
 
-    * rollout workers are host-side sampler processes — default them to
-      the CPU client unless a platform is pinned explicitly
+    * rollout workers are host-side sampler processes and always run on
+      the CPU client: an accelerator belongs to one process at a time,
+      and the learner (this process) already holds it
     * limit each worker's XLA CPU intra-op pool to one thread: N workers
       x one multi-threaded eigen pool oversubscribes small hosts and
       *slows* collection as N grows (bitwise-neutral for rollout-sized
@@ -475,8 +476,7 @@ def _worker_env():
       parent multi-threaded)
     """
     saved = {k: os.environ.get(k) for k in ("JAX_PLATFORMS", "XLA_FLAGS")}
-    if saved["JAX_PLATFORMS"] is None:
-        os.environ["JAX_PLATFORMS"] = "cpu"
+    os.environ["JAX_PLATFORMS"] = "cpu"
     flags = saved["XLA_FLAGS"] or ""
     if "intra_op_parallelism_threads" not in flags:
         os.environ["XLA_FLAGS"] = (
@@ -558,7 +558,7 @@ def _worker_main(spec_dict: Dict[str, Any], ring_spec: RingSpec,
                 f"{len(chan.spec.leaves)} — WorkerSpec and learner params "
                 f"disagree")
         hb.beat(worker_id)
-        res_q.put(("ready", worker_id))
+        res_q.put(("ready", worker_id, jax.devices()[0].platform))
 
         params, last_version = None, -1
         freerunning, counter, stop = False, 0, False
@@ -719,16 +719,17 @@ class ProcessWorkerPool:
             raise ValueError(
                 f"active_workers {self.active} out of range for "
                 f"{self.max_workers} specs")
+        # each started worker's own report of its JAX platform ("cpu")
+        self.worker_platforms: Dict[int, str] = {}
         self._atexit = lambda: self.close(raise_on_crash=False)
         atexit.register(self._atexit)
         try:
             for i in self.active:
                 self._spawn(i)
-            ready = set()
-            while len(ready) < len(self.active):
+            while len(self.worker_platforms) < len(self.active):
                 msg = self._get(timeout=start_timeout)
                 if msg[0] == "ready":
-                    ready.add(msg[1])
+                    self.worker_platforms[msg[1]] = msg[2]
         except BaseException:
             self.close(raise_on_crash=False)
             raise

@@ -127,12 +127,10 @@ def tree_ready(tree) -> bool:
     """True iff every device array in ``tree`` has finished computing
     (``jax.Array.is_ready``) — a non-blocking probe used by the overlap
     pipeline to tell whether the in-flight learn was still running when
-    the concurrent collect finished."""
-    try:
-        return all(bool(leaf.is_ready()) for leaf in jax.tree.leaves(tree)
-                   if hasattr(leaf, "is_ready"))
-    except Exception:
-        return False
+    the concurrent collect finished. A device error raises here rather
+    than reading as "not ready"."""
+    return all(bool(leaf.is_ready()) for leaf in jax.tree.leaves(tree)
+               if hasattr(leaf, "is_ready"))
 
 
 class OverlapClock:
@@ -240,6 +238,8 @@ class SyncRunner(BackendCloseMixin):
         #                                   runner, not once per run() call
         self.timer = PhaseTimer()
         self.logs: List[IterationLog] = []
+        self.metrics: Dict[str, Any] = {}  # last learner step's metrics
+        #                                    (loss terms), device-resident
 
     @property
     def buffer_state(self):
@@ -260,12 +260,13 @@ class SyncRunner(BackendCloseMixin):
         for it in range(iterations):
             merged, stats = self.backend.collect(self._rollout_params())
             if self._train_step is not None:
-                (self.params, self.opt_state, self.plane_state, _,
+                (self.params, self.opt_state, self.plane_state, self.metrics,
                  learn_time) = timed_train_step(
                      self._train_step, self.params, self.opt_state,
                      self.plane_state, merged)
             else:
-                self.params, self.opt_state, _, learn_time = timed_learn(
+                (self.params, self.opt_state, self.metrics,
+                 learn_time) = timed_learn(
                     self.learn, self.params, self.opt_state, merged)
             self._pin()
             record_log(self.logs, self.timer,
@@ -299,7 +300,7 @@ class SyncRunner(BackendCloseMixin):
             warm, self._overlap_done = (self._overlap_done,
                                         self._overlap_done + 1)
             if warm < self._OVERLAP_WARMUP:
-                (self.params, self.opt_state, self.plane_state, _,
+                (self.params, self.opt_state, self.plane_state, self.metrics,
                  learn_time) = timed_train_step(
                      self._train_step, self.params, self.opt_state,
                      self.plane_state, merged)
@@ -327,7 +328,7 @@ class SyncRunner(BackendCloseMixin):
                 saved = clock.saved(max(nstats.per_sampler_seconds),
                                     tree_ready(out[0]))
                 pending = (nxt, nstats, 1.0)
-            self.params, self.opt_state, self.plane_state, _ = out
+            self.params, self.opt_state, self.plane_state, self.metrics = out
             jax.block_until_ready(self.params)
             window = time.perf_counter() - t0
             self._pin()

@@ -149,8 +149,6 @@ def make_sharded_rollout(env: Env, horizon: int, mesh,
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.distributed.sharding import shard_map_compat
-
     if rollout is None:
         rollout = make_env_rollout(env, horizon)
     batch_spec = P(data_axes)                      # leading dim = env batch
@@ -159,12 +157,10 @@ def make_sharded_rollout(env: Env, horizon: int, mesh,
     traj_spec = {k: P(None, data_axes) for k in step_keys}
     traj_spec.update({k: batch_spec for k in tail_keys})
 
-    sharded = shard_map_compat(
-        lambda p, c: rollout(p, c),
-        mesh,
-        (P(), carry_spec),
-        (carry_spec, traj_spec),
-    )
+    sharded = jax.shard_map(
+        lambda p, c: rollout(p, c), mesh=mesh,
+        in_specs=(P(), carry_spec), out_specs=(carry_spec, traj_spec),
+        check_vma=False)
     return sharded
 
 

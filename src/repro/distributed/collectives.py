@@ -57,5 +57,5 @@ def flash_decode_shardmap(mesh: Mesh, axis: str = "model"):
 
     in_specs = (P(), P(None, axis, None, None), P(None, axis, None, None),
                 P(axis))
-    from repro.distributed.sharding import shard_map_compat
-    return shard_map_compat(local, mesh, in_specs, P())
+    return jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                         out_specs=P(), check_vma=False)

@@ -1,7 +1,7 @@
 """The multi-device learner plane: shard_map data-parallel training.
 
 ``ShardedLearner`` wraps any shardable ``Algorithm``'s composed train
-step (``algos.api.make_train_step``) in ``shard_map_compat`` over a
+step (``algos.api.make_train_step``) in ``jax.shard_map`` over a
 learner mesh:
 
 * trajectories / replay minibatches shard along the mesh's batch axes
@@ -54,7 +54,6 @@ from repro.distributed.sharding import (
     axes_size,
     batch_axes,
     fsdp_leaf_dim,
-    shard_map_compat,
 )
 
 
@@ -220,10 +219,10 @@ class ShardedLearner:
             self._sharding_tree(s, t)
             for s, t in zip((pspec, ospec, plane_spec, traj_spec),
                             (params, opt_state, plane, traj)))
-        wrapped = shard_map_compat(
-            local_step, self.mesh,
-            (pspec, ospec, plane_spec, traj_spec),
-            (pspec, ospec, plane_spec, P()))
+        wrapped = jax.shard_map(
+            local_step, mesh=self.mesh,
+            in_specs=(pspec, ospec, plane_spec, traj_spec),
+            out_specs=(pspec, ospec, plane_spec, P()), check_vma=False)
         self._jitted = jax.jit(wrapped)
         return wrapped
 

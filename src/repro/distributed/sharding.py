@@ -25,20 +25,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
-# ---------------------------------------------------------------- compat
-def shard_map_compat(f, mesh, in_specs, out_specs):
-    """shard_map across JAX versions: >=0.5 exposes
-    ``jax.shard_map(check_vma=...)``; 0.4.x has the experimental module
-    with ``check_rep``. Replication checking is disabled either way (the
-    rollout/serving bodies use collectives the checker can't follow)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
-
-
 # ------------------------------------------------------------------ axes
 def fsdp_axes(mesh: Mesh) -> Tuple[str, ...]:
     return tuple(a for a in ("pod", "data") if a in mesh.axis_names)

@@ -23,10 +23,8 @@ sampling needs ``jax.random``; ``envs.base.auto_reset_batch`` draws them
 outside) and ``done`` is returned as an int32 0/1 mask (the dispatcher
 restores bool) — booleans stay internal to the kernel.
 
-No scratch buffers or TPU-specific memory spaces are used, so the same
-kernel bodies lower via Mosaic on TPU and Triton on GPU
-(``kernels/select.py`` compiles Pallas on both; interpret mode remains
-the CPU correctness harness).
+The kernels compile through Mosaic on TPU (``kernels/select.py``);
+interpret mode is the CPU correctness harness.
 """
 from __future__ import annotations
 

@@ -42,7 +42,7 @@ def env_step(name: str, state, actions, reset_state, reset_obs, *,
     if name not in ref.STEP_BATCH_REF:
         raise KeyError(f"no env_step kernels for env {name!r}; "
                        f"choose from {sorted(ref.STEP_BATCH_REF)}")
-    impl_name, interpret = select.resolve(impl)
+    impl_name, interpret = select.resolve(impl, "env_step.step")
     if impl_name == "pallas" and jnp.dtype(dtype) == jnp.float32:
         return env_step_pallas.STEP_BATCH_PALLAS[name](
             state, actions, reset_state, reset_obs,

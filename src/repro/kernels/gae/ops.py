@@ -32,7 +32,7 @@ def gae(rewards: jnp.ndarray, values: jnp.ndarray, dones: jnp.ndarray,
         *, impl: Optional[str] = None
         ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Advantages + returns; see ``ref.gae_ref`` for semantics."""
-    name, interpret = select.resolve(impl)
+    name, interpret = select.resolve(impl, "gae.gae")
     if name == "ref":
         return gae_ref(rewards, values, dones, last_value, gamma, lam)
     nonterm = 1.0 - dones.astype(jnp.float32)
@@ -47,7 +47,7 @@ def discounted_returns(rewards: jnp.ndarray, dones: jnp.ndarray,
                        last_value: jnp.ndarray, gamma: float = 0.99,
                        *, impl: Optional[str] = None) -> jnp.ndarray:
     """Discounted returns-to-go; see ``ref.discounted_returns_ref``."""
-    name, interpret = select.resolve(impl)
+    name, interpret = select.resolve(impl, "gae.discounted_returns")
     if name == "ref":
         return discounted_returns_ref(rewards, dones, last_value, gamma)
     nonterm = 1.0 - dones.astype(jnp.float32)

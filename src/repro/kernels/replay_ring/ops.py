@@ -28,7 +28,7 @@ def ring_insert(storage: Dict[str, jnp.ndarray],
                 batch: Dict[str, jnp.ndarray], start: jnp.ndarray, *,
                 impl: Optional[str] = None) -> Dict[str, jnp.ndarray]:
     """Scatter-insert (N, ...) transitions at the ring head (wraps)."""
-    name, interpret = select.resolve(impl)
+    name, interpret = select.resolve(impl, "replay_ring.insert")
     if name == "ref":
         return ring_insert_ref(storage, batch, start)
     return {
@@ -43,7 +43,7 @@ def ring_insert(storage: Dict[str, jnp.ndarray],
 def ring_gather(storage: Dict[str, jnp.ndarray], idx: jnp.ndarray, *,
                 impl: Optional[str] = None) -> Dict[str, jnp.ndarray]:
     """Draw the rows at ``idx`` (B,) from every leaf."""
-    name, interpret = select.resolve(impl)
+    name, interpret = select.resolve(impl, "replay_ring.gather")
     if name == "ref":
         return ring_gather_ref(storage, idx)
     return {

@@ -8,9 +8,16 @@ flattens trailing dims):
   head with wraparound, rows streamed through VMEM in one launch instead
   of an XLA scatter per leaf. Sequential row writes make duplicate
   positions (N > capacity) resolve last-write-wins, matching the
-  reference's in-order scatter.
-* ``ring_gather_pallas`` — the stratified/uniform minibatch draw: B
-  dynamic row gathers in one launch.
+  reference's in-order scatter. It holds the whole ring in VMEM, so the
+  TPU compiler refuses it at 10^6 rows, and below that it moves the
+  whole ring per insert; on TPU the reference runs instead
+  (``kernels.select.TPU_REFUSED``).
+* ``ring_gather_pallas`` — the stratified/uniform minibatch draw: one
+  grid step per drawn row. The drawn indices are scalar-prefetched into
+  SMEM, and the ring stays in HBM: each step's ``index_map`` reads the
+  index and DMAs the one sublane-aligned block of rows that holds it
+  (rows narrower than 128 lanes cannot be DMAed one at a time), and the
+  kernel copies the row out of that block.
 
 Both kernels only move bytes — no arithmetic — so parity with the
 reference is exact for every dtype.
@@ -22,6 +29,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _insert_kernel(start_ref, storage_ref, batch_ref, out_ref, *,
@@ -37,12 +45,15 @@ def _insert_kernel(start_ref, storage_ref, batch_ref, out_ref, *,
     jax.lax.fori_loop(0, n, write, 0)
 
 
-def _gather_kernel(idx_ref, storage_ref, out_ref, *, batch: int):
-    def read(j, _):
-        out_ref[pl.ds(j, 1), :] = storage_ref[pl.ds(idx_ref[0, j], 1), :]
-        return 0
+def _gather_kernel(idx_ref, block_ref, out_ref, *, rows: int):
+    j = pl.program_id(0)
+    out_ref[pl.ds(j % rows, 1), :] = block_ref[pl.ds(idx_ref[j] % rows, 1), :]
 
-    jax.lax.fori_loop(0, batch, read, 0)
+
+def _sublane_rows(dtype) -> int:
+    """Rows in one (sublane x 128-lane) tile of ``dtype``: 8 for 32-bit,
+    16 for 16-bit, 32 for 8-bit elements."""
+    return 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
 
 
 def ring_insert_pallas(storage: jnp.ndarray, batch: jnp.ndarray,
@@ -73,13 +84,19 @@ def ring_gather_pallas(storage: jnp.ndarray, idx: jnp.ndarray, *,
     """storage (cap, D), idx (B,) int32 -> rows (B, D)."""
     cap, feat = storage.shape
     B = idx.shape[0]
-    kernel = functools.partial(_gather_kernel, batch=B)
-    return pl.pallas_call(
-        kernel,
-        grid=(1,),
-        in_specs=[pl.BlockSpec((1, B), lambda i: (0, 0)),
-                  pl.BlockSpec((cap, feat), lambda i: (0, 0))],
-        out_specs=pl.BlockSpec((B, feat), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, feat), storage.dtype),
+    rows = _sublane_rows(storage.dtype)
+    bp = pl.cdiv(B, rows) * rows
+    idx = jnp.pad(idx.astype(jnp.int32), (0, bp - B))
+    out = pl.pallas_call(
+        functools.partial(_gather_kernel, rows=rows),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(bp,),
+            in_specs=[pl.BlockSpec((rows, feat),
+                                   lambda j, idx: (idx[j] // rows, 0))],
+            out_specs=pl.BlockSpec((rows, feat),
+                                   lambda j, idx: (j // rows, 0))),
+        out_shape=jax.ShapeDtypeStruct((bp, feat), storage.dtype),
         interpret=interpret,
-    )(idx[None, :].astype(jnp.int32), storage)
+    )(idx, storage)
+    return out[:B]

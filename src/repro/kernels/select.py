@@ -9,20 +9,19 @@ decided here:
   CPU, and the implementation every bitwise guarantee in the test suite
   (``ppo`` × ``inline`` legacy identity, ``fused == stepped``) is stated
   against.
-* ``pallas`` — always the Pallas kernel. On an accelerator (TPU via
-  Mosaic, GPU via Triton) the kernel compiles; on CPU it runs in
-  interpret mode (a correctness harness, not a timing one), so parity
-  tests exercise the real kernel bodies on CPU CI.
-* ``auto``   — ``pallas`` compiled on TPU *and* GPU, ``ref`` on CPU.
-  The default: experiments pick up the kernels exactly where they pay
-  off and stay on the oracle (and bitwise-stable) elsewhere.
+* ``pallas`` — always the Pallas kernel. On TPU the kernel compiles
+  through Mosaic; on any other platform it runs in interpret mode (a
+  correctness harness, not a timing one), so parity tests exercise the
+  real kernel bodies on CPU CI.
+* ``auto``   — ``pallas`` compiled on TPU, ``ref`` elsewhere, except the
+  kernels listed in ``TPU_REFUSED``, which stay on ``ref`` on TPU too.
 
-The selection table (backend × mode -> implementation, interpret flag):
+The selection table (platform × mode -> implementation):
 
-    mode     cpu               tpu / gpu
-    ref      ref               ref
-    pallas   pallas+interpret  pallas compiled
-    auto     ref               pallas compiled
+    mode     cpu / gpu          tpu
+    ref      ref                ref
+    pallas   pallas+interpret   pallas compiled (TPU_REFUSED: error)
+    auto     ref                pallas compiled (TPU_REFUSED: ref)
 
 The mode is process-global and read at **trace time**: dispatchers
 branch when a train step is traced, so already-jitted callables keep the
@@ -40,10 +39,29 @@ import jax
 
 MODES = ("ref", "pallas", "auto")
 
-# platforms where Pallas kernels compile to native code: TPU lowers via
-# Mosaic, GPU via Triton (jax reports "gpu" for CUDA/ROCm builds, but
-# accept the vendor spellings too)
-COMPILED_PLATFORMS = ("tpu", "gpu", "cuda", "rocm")
+# the one platform whose Pallas lowering (Mosaic) the kernels are written
+# for; they use TPU memory spaces (SMEM scalar prefetch, HBM refs)
+COMPILED_PLATFORMS = ("tpu",)
+
+# Kernels with no Pallas form that compiles on TPU at the replay sizes
+# the system runs, keyed by ``<family>.<op>``, with the compiler's reason
+# (checked for a described v5e chip). ``auto`` resolves them to
+# ``ref`` on TPU (XLA's own scatter, in place under donation); ``pallas``
+# on TPU raises with the reason. Both stay testable in interpret mode.
+TPU_REFUSED = {
+    "sum_tree.update": (
+        "Mosaic refuses the pairwise parent rebuild: 'Shape mismatch in "
+        "input, indices and output' (the child[:, 0::2] + child[:, 1::2] "
+        "lane-strided slices), at every capacity"),
+    "replay_ring.insert": (
+        "the kernel holds the whole ring as one VMEM block: it compiles "
+        "up to (131072, 14) f32 but asks for 512 MB at (10^6, 17) "
+        "against 128 MiB of VMEM, and where it compiles it streams the "
+        "whole ring through VMEM on every insert, where the reference "
+        "updates n rows in place; a per-row HBM DMA of a ring narrower "
+        "than 128 lanes is refused: 'Slice shape along dimension 1 must "
+        "be aligned to tiling (128)'"),
+}
 
 _mode = "auto"
 
@@ -61,21 +79,30 @@ def kernel_mode() -> str:
     return _mode
 
 
-def resolve(impl: Optional[str] = None) -> Tuple[str, bool]:
-    """Resolve a per-call override (or the global mode) to a concrete
-    implementation: ``("ref", False)`` or ``("pallas", interpret)``.
+def resolve(impl: Optional[str] = None,
+            kernel: Optional[str] = None) -> Tuple[str, bool]:
+    """Resolve a per-call override (or the global mode) for ``kernel``
+    (``<family>.<op>``) to a concrete implementation: ``("ref", False)``
+    or ``("pallas", interpret)``.
 
     ``interpret`` is True whenever the Pallas kernel would run on a
-    platform with no native lowering (CPU) — the interpreter executes
-    the kernel body with real JAX ops, so the result is exact but the
-    timing is meaningless. On TPU and GPU the kernels compile.
+    platform with no native lowering — the interpreter executes the
+    kernel body with real JAX ops, so the result is exact but the timing
+    is meaningless. On TPU the kernels compile, and a kernel in
+    ``TPU_REFUSED`` resolves to ``ref`` under ``auto`` and raises under
+    ``pallas``.
     """
     mode = impl if impl is not None else _mode
     if mode not in MODES:
         raise ValueError(f"unknown kernel impl {mode!r}; choose from {MODES}")
     compiled = jax.default_backend() in COMPILED_PLATFORMS
+    refused = compiled and kernel in TPU_REFUSED
     if mode == "auto":
-        mode = "pallas" if compiled else "ref"
+        mode = "pallas" if compiled and not refused else "ref"
     if mode == "ref":
         return "ref", False
+    if refused:
+        raise ValueError(
+            f"kernel {kernel!r} has no Pallas form that compiles on TPU "
+            f"({TPU_REFUSED[kernel]}); use kernels='auto' or 'ref'")
     return "pallas", not compiled

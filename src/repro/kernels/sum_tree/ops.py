@@ -43,7 +43,7 @@ def tree_unflatten(flat: jnp.ndarray, capacity: int) -> SumTree:
 def sumtree_find_batch(tree: SumTree, masses: jnp.ndarray, *,
                        impl: Optional[str] = None) -> jnp.ndarray:
     """Stratified descent for a batch of masses -> leaf indices (B,)."""
-    name, interpret = select.resolve(impl)
+    name, interpret = select.resolve(impl, "sum_tree.find")
     if name == "ref":
         return sumtree_find_batch_ref(tree, masses)
     capacity = tree.levels[0].shape[0]
@@ -55,7 +55,7 @@ def sumtree_update(tree: SumTree, idx: jnp.ndarray,
                    leaf_values: jnp.ndarray, *,
                    impl: Optional[str] = None) -> SumTree:
     """Batched leaf write-back + parent recomputation."""
-    name, interpret = select.resolve(impl)
+    name, interpret = select.resolve(impl, "sum_tree.update")
     if name == "ref":
         return sumtree_update_ref(tree, idx, leaf_values)
     capacity = tree.levels[0].shape[0]

@@ -5,11 +5,13 @@ Prioritized replay's two hot paths, each as one kernel launch over a
 static, derived from the capacity):
 
 * ``sumtree_find_pallas``  — the full stratified root-to-leaf descent
-  for a batch of B masses. The tree lives in VMEM for the whole walk
-  (O(2·cap) floats — the only large buffer) and each sample walks
-  root-to-leaf with ``log2(cap)`` scalar reads, so the launch does
-  O(B·log cap) work instead of ``log2(cap)`` separately scheduled
-  host-side gathers.
+  for a batch of B masses. The tree stays in HBM, so capacity is not
+  capped by VMEM; masses and the walked indices live in SMEM (scalar
+  prefetch). Each level, a group of samples DMAs the 1024-element run
+  of the flat tree holding its left child into SMEM (1024 elements is
+  the smallest slice a DMA may take of a 1-D f32 array in HBM) and
+  steps down, so the launch does B·log2(cap) dependent scalar steps
+  with the DMAs of a group in flight together.
 * ``sumtree_update_pallas`` — the batched priority write-back: a
   sequential last-write-wins leaf scatter (matching XLA's in-order
   ``.at[idx].set`` semantics under duplicates) followed by a pairwise
@@ -17,6 +19,8 @@ static, derived from the capacity):
   Assumes the input tree is consistent (every parent the pairwise sum of
   its children — guaranteed by construction), in which case the rebuild
   is bitwise-identical to the reference's touched-path recomputation.
+  The TPU compiler refuses its lane-strided slices, so it runs only in
+  interpret mode (``kernels.select.TPU_REFUSED``).
 
 Both kernels evaluate the reference expressions exactly, so parity tests
 assert equality, not closeness.
@@ -29,6 +33,13 @@ from typing import Sequence, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+# a 1-D f32 array in HBM is tiled in runs of 1024 elements: the
+# smallest slice a DMA may take of the flat tree
+_CHUNK = 1024
+# samples whose level reads are in flight together
+_GROUP = 8
 
 
 def level_sizes(capacity: int) -> Tuple[int, ...]:
@@ -54,22 +65,39 @@ def level_offsets(sizes: Sequence[int]) -> Tuple[int, ...]:
     return tuple(offs)
 
 
-def _find_kernel(flat_ref, m_ref, idx_ref, *, sizes, offsets, batch: int):
-    num_levels = len(sizes)
-
-    def walk(j, _):
-        idx = jnp.zeros((), jnp.int32)
-        mass = m_ref[0, j]
-        for k in range(num_levels - 2, -1, -1):
-            idx = idx * 2
-            left = flat_ref[0, offsets[k] + idx]
-            go_right = mass >= left
-            mass = jnp.where(go_right, mass - left, mass)
-            idx = jnp.where(go_right, idx + 1, idx)
-        idx_ref[0, j] = idx
+def _find_kernel(m_ref, flat_hbm, idx_ref, mass_ref, buf_ref, sem, *,
+                 offsets, groups: int):
+    def init(j, _):
+        idx_ref[j] = jnp.int32(0)
+        mass_ref[j] = m_ref[j]
         return 0
 
-    jax.lax.fori_loop(0, batch, walk, 0)
+    jax.lax.fori_loop(0, groups * _GROUP, init, 0)
+
+    def chunk_copy(s, base):
+        return pltpu.make_async_copy(
+            flat_hbm.at[pl.ds(base, _CHUNK)],
+            buf_ref.at[pl.ds(s * _CHUNK, _CHUNK)], sem)
+
+    def descend(g, _):
+        for off in offsets[-2::-1]:          # root's children -> leaves
+            for s in range(_GROUP):
+                pos = off + 2 * idx_ref[g * _GROUP + s]
+                chunk_copy(s, pl.multiple_of(pos // _CHUNK * _CHUNK,
+                                             _CHUNK)).start()
+            for s in range(_GROUP):
+                chunk_copy(s, 0).wait()
+            for s in range(_GROUP):
+                j = g * _GROUP + s
+                idx = 2 * idx_ref[j]
+                left = buf_ref[s * _CHUNK + (off + idx) % _CHUNK]
+                mass = mass_ref[j]
+                go_right = mass >= left
+                mass_ref[j] = jnp.where(go_right, mass - left, mass)
+                idx_ref[j] = jnp.where(go_right, idx + 1, idx)
+        return 0
+
+    jax.lax.fori_loop(0, groups, descend, 0)
 
 
 def _update_kernel(flat_ref, idx_ref, vals_ref, out_ref, *, sizes, offsets,
@@ -94,22 +122,28 @@ def sumtree_find_pallas(flat: jnp.ndarray, masses: jnp.ndarray, *,
                         ) -> jnp.ndarray:
     """flat (2*cap-1,) f32 (leaves-first levels), masses (B,) f32
     -> leaf indices (B,) int32."""
-    sizes = level_sizes(capacity)
-    offsets = level_offsets(sizes)
+    offsets = level_offsets(level_sizes(capacity))
     (total,) = flat.shape
     B = masses.shape[0]
-    kernel = functools.partial(_find_kernel, sizes=sizes, offsets=offsets,
-                               batch=B)
+    groups = pl.cdiv(B, _GROUP)
+    bp = groups * _GROUP
+    flat = jnp.pad(flat, (0, pl.cdiv(total, _CHUNK) * _CHUNK - total))
+    masses = jnp.pad(masses.astype(jnp.float32), (0, bp - B))
+    kernel = functools.partial(_find_kernel, offsets=offsets, groups=groups)
     idx = pl.pallas_call(
         kernel,
-        grid=(1,),
-        in_specs=[pl.BlockSpec((1, total), lambda i: (0, 0)),
-                  pl.BlockSpec((1, B), lambda i: (0, 0))],
-        out_specs=pl.BlockSpec((1, B), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((1, B), jnp.int32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(1,),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec(memory_space=pltpu.SMEM),
+            scratch_shapes=[pltpu.SMEM((bp,), jnp.float32),
+                            pltpu.SMEM((_GROUP * _CHUNK,), jnp.float32),
+                            pltpu.SemaphoreType.DMA(())]),
+        out_shape=jax.ShapeDtypeStruct((bp,), jnp.int32),
         interpret=interpret,
-    )(flat[None, :], masses[None, :])
-    return idx[0]
+    )(masses, flat)
+    return idx[:B]
 
 
 def sumtree_update_pallas(flat: jnp.ndarray, idx: jnp.ndarray,

@@ -15,6 +15,7 @@ import jax.numpy as jnp
 
 from repro.configs import get_config
 from repro.envs import lm_env
+from repro.launch import compile_cache
 from repro.models import transformer
 
 
@@ -43,6 +44,7 @@ def main() -> None:
     ap.add_argument("--requests", type=int, default=3)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    compile_cache.enable()
 
     cfg = get_config(args.arch)
     key = jax.random.PRNGKey(args.seed)

@@ -35,6 +35,7 @@ import jax
 import numpy as np
 
 from repro.core.ipc import ChannelSpec, ParamsChannel
+from repro.launch import compile_cache
 from repro.serve import PolicyServer, load_policy
 
 
@@ -98,6 +99,7 @@ def main(argv=None) -> None:
                          "path to its ChannelSpec JSON handoff file")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     handle = load_policy(args.ckpt, args.step)
     print(f"# serving {handle.name} from {args.ckpt} "

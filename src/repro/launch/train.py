@@ -44,6 +44,7 @@ from repro.algos.ppo import PPOConfig, make_lm_train_step
 from repro.checkpoint import save
 from repro.configs import get_config
 from repro.experiment import ExperimentSpec, Schedule
+from repro.launch import compile_cache
 from repro.models import transformer
 from repro.optim import adam
 
@@ -269,6 +270,7 @@ def main() -> None:
                     help="per-version-gap decay factor (default 0.9)")
     ap.add_argument("--ckpt-dir", default=None)
     args = ap.parse_args()
+    compile_cache.enable()
     (run_rl if args.mode == "rl" else run_lm)(args)
 
 

@@ -227,7 +227,8 @@ def test_env_step_non_f32_falls_back_to_ref():
 
 # ========================================================= selection table
 # mode × platform -> (implementation, interpret): auto compiles Pallas on
-# both TPU (Mosaic) and GPU (Triton); interpret only off-accelerator.
+# TPU (Mosaic) only — the kernels use TPU memory spaces — and every other
+# platform interprets them.
 @pytest.mark.parametrize("platform,mode,expect", [
     ("cpu", "ref", ("ref", False)),
     ("cpu", "pallas", ("pallas", True)),
@@ -236,10 +237,10 @@ def test_env_step_non_f32_falls_back_to_ref():
     ("tpu", "pallas", ("pallas", False)),
     ("tpu", "auto", ("pallas", False)),
     ("gpu", "ref", ("ref", False)),
-    ("gpu", "pallas", ("pallas", False)),
-    ("gpu", "auto", ("pallas", False)),
-    ("cuda", "auto", ("pallas", False)),
-    ("rocm", "auto", ("pallas", False)),
+    ("gpu", "pallas", ("pallas", True)),
+    ("gpu", "auto", ("ref", False)),
+    ("cuda", "auto", ("ref", False)),
+    ("rocm", "auto", ("ref", False)),
 ])
 def test_selection_table(monkeypatch, platform, mode, expect):
     monkeypatch.setattr(select.jax, "default_backend", lambda: platform)

@@ -2,6 +2,7 @@
 primitives, ``process == inline`` determinism, worker-crash surfacing and
 lifecycle (close/reap) semantics."""
 import json
+import os
 
 import jax
 import numpy as np
@@ -169,3 +170,14 @@ def test_run_reaps_workers_and_close_is_idempotent():
     assert all(log.samples == TINY["global_batch"] * TINY["horizon"]
                for log in res.logs)
     assert np.isfinite(res.logs[-1].mean_return)
+
+
+def test_worker_env_pins_children_to_cpu(monkeypatch):
+    """A worker spawned while the learner's environment names an
+    accelerator still gets the CPU client (the learner holds the chip);
+    the parent's environment is restored after the spawn."""
+    from repro.core import ipc
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu")
+    with ipc._worker_env():
+        assert os.environ["JAX_PLATFORMS"] == "cpu"
+    assert os.environ["JAX_PLATFORMS"] == "tpu"

@@ -31,7 +31,6 @@ from repro.distributed.replay_sharded import (
     ShardedUniformBuffer,
     shard_buffer,
 )
-from repro.distributed.sharding import shard_map_compat
 from repro.kernels.sum_tree import sumtree_update_masked
 from repro.kernels.sum_tree.ref import sumtree_update_ref
 
@@ -130,7 +129,8 @@ def test_sharded_prioritized_d1_mesh_bitwise():
     spec = buf.state_spec(state_sh)
     out_spec = {k: P(("data",))
                 for k in ("obs", "rewards", "indices", "weights")}
-    sample = shard_map_compat(buf.sample, _mesh1(), (spec, P()), out_spec)
+    sample = jax.shard_map(buf.sample, mesh=_mesh1(), in_specs=(spec, P()),
+                           out_specs=out_spec, check_vma=False)
     key = jax.random.PRNGKey(7)
     got = sample(state_sh, key)
     want = PrioritizedBuffer(cap, batch).sample(state_ref, key)
@@ -152,7 +152,8 @@ def test_sharded_uniform_d1_mesh_bitwise():
     spec = buf.state_spec(state)
     out_spec = {k: P(("data",))
                 for k in ("obs", "rewards", "indices", "weights")}
-    sample = shard_map_compat(buf.sample, _mesh1(), (spec, P()), out_spec)
+    sample = jax.shard_map(buf.sample, mesh=_mesh1(), in_specs=(spec, P()),
+                           out_specs=out_spec, check_vma=False)
     key = jax.random.PRNGKey(11)
     got = sample(state, key)
     want = UniformBuffer(cap, batch).sample(state, key)
@@ -169,8 +170,9 @@ def test_sharded_prioritized_d1_mesh_update_priorities():
     spec = buf.state_spec(state_sh)
     idx = jnp.asarray(np.random.RandomState(4).permutation(cap)[:batch])
     pri = (idx.astype(jnp.float32) % 7 + 1.0) * 0.3
-    upd = shard_map_compat(buf.update_priorities, _mesh1(),
-                           (spec, P(("data",)), P(("data",))), spec)
+    upd = jax.shard_map(buf.update_priorities, mesh=_mesh1(),
+                        in_specs=(spec, P(("data",)), P(("data",))),
+                        out_specs=spec, check_vma=False)
     got = upd(state_sh, idx, pri)
     want = PrioritizedBuffer(cap, batch).update_priorities(
         state_ref, idx, pri)
@@ -198,7 +200,6 @@ from repro.data import replay
 from repro.data.buffers import PrioritizedBuffer, PrioritizedState, \
     SumTree, sumtree_build
 from repro.distributed.replay_sharded import ShardedPrioritizedBuffer
-from repro.distributed.sharding import shard_map_compat
 
 cap, batch, d = 32, 16, 4
 c_loc = cap // d
@@ -225,7 +226,8 @@ mesh = Mesh(np.asarray(jax.devices()[:d]).reshape(d, 1), ("data", "model"))
 spec = buf.state_spec(state_sh)
 out_spec = {k: P(("data",)) for k in ("obs", "rewards", "indices",
                                       "weights")}
-sample = shard_map_compat(buf.sample, mesh, (spec, P()), out_spec)
+sample = jax.shard_map(buf.sample, mesh=mesh, in_specs=(spec, P()),
+                       out_specs=out_spec, check_vma=False)
 
 key = jax.random.PRNGKey(7)
 got = sample(state_sh, key)
@@ -259,8 +261,9 @@ np.testing.assert_array_equal(realized, np.bincount(owner, minlength=d))
 # priority write-back: same leaves, same values, same max_priority
 idx = jnp.asarray(want["indices"])
 pri = (idx.astype(jnp.float32) % 7 + 1.0) * 0.3
-upd = shard_map_compat(buf.update_priorities, mesh,
-                       (spec, P(("data",)), P(("data",))), spec)
+upd = jax.shard_map(buf.update_priorities, mesh=mesh,
+                    in_specs=(spec, P(("data",)), P(("data",))),
+                    out_specs=spec, check_vma=False)
 got_st = upd(state_sh, idx, pri)
 want_st = ref.update_priorities(state_ref, idx, pri)
 np.testing.assert_array_equal(np.asarray(got_st.tree.levels[0]),

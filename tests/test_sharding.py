@@ -10,17 +10,8 @@ from repro.distributed import sharding as sh
 from repro.launch import specs as specs_mod
 from repro.models import transformer
 
-def _abstract_mesh(sizes, names):
-    """AbstractMesh across JAX versions: >=0.5 takes (axis_sizes,
-    axis_names); 0.4.x takes a single ((name, size), ...) tuple."""
-    try:
-        return AbstractMesh(sizes, names)
-    except TypeError:
-        return AbstractMesh(tuple(zip(names, sizes)))
-
-
-MESH_SP = _abstract_mesh((16, 16), ("data", "model"))
-MESH_MP = _abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+MESH_SP = AbstractMesh((16, 16), ("data", "model"))
+MESH_MP = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def test_shard_axes_divisibility_fallback():
