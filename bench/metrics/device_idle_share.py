@@ -1,0 +1,7 @@
+"""Share of the traced window in which no operation ran on the device:
+1 - (union of the device's op intervals) / window, averaged over the
+chips the cell uses."""
+
+
+def read(ctx):
+    return 100.0 * (1.0 - ctx.trace.busy_s / ctx.trace.window_s)

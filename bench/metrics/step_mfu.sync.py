@@ -1,0 +1,8 @@
+"""``step_mfu`` in the cells that report ``env_steps_per_s.sync``: the
+same reading (``metrics/step_mfu.py``), kept apart because those cells' rate
+is bounded apart."""
+import pathlib
+
+from bench import harness
+
+read = harness.load_module(pathlib.Path(__file__).with_name("step_mfu.py")).read
