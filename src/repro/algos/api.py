@@ -197,34 +197,48 @@ def make_train_step(algo, buffer) -> Callable:
     step collapses to exactly the historical ``learn(params, opt_state,
     traj)`` call — no scan, no PRNG consumption — which keeps ``ppo`` ×
     ``inline`` bitwise-identical to the pre-plane path.
+
+    The device trace names each part by its scope: ``replay.add``,
+    ``replay.sample``, ``replay.update_priorities`` and ``learner.update``.
     """
     updates = int(getattr(algo, "updates_per_collect", 1))
+
+    def observe(buf_state, traj):
+        with jax.named_scope("replay.add"):
+            return algo.observe(buffer, buf_state, traj)
+
+    def sample(buf_state, key):
+        with jax.named_scope("replay.sample"):
+            return algo.sample(buffer, buf_state, key)
+
+    def learn(params, opt_state, batch):
+        with jax.named_scope("learner.update"):
+            return algo.learn(params, opt_state, batch)
 
     if getattr(buffer, "passthrough", False) and updates == 1:
         def step(params, opt_state, plane, traj):
             buf_state, key = plane
-            buf_state = algo.observe(buffer, buf_state, traj)
-            batch = algo.sample(buffer, buf_state, key)
-            params, opt_state, metrics = algo.learn(params, opt_state,
-                                                    batch)
+            buf_state = observe(buf_state, traj)
+            batch = sample(buf_state, key)
+            params, opt_state, metrics = learn(params, opt_state, batch)
             return params, opt_state, (buf_state, key), metrics
         return step
 
     def step(params, opt_state, plane, traj):
         buf_state, key = plane
-        buf_state = algo.observe(buffer, buf_state, traj)
+        buf_state = observe(buf_state, traj)
         keys = jax.random.split(key, updates + 1)
 
         def one(carry, k):
             params, opt_state, buf_state = carry
-            batch = algo.sample(buffer, buf_state, k)
-            params, opt_state, metrics = algo.learn(params, opt_state,
-                                                    batch)
+            batch = sample(buf_state, k)
+            params, opt_state, metrics = learn(params, opt_state, batch)
             metrics = dict(metrics)
             priorities = metrics.pop("priorities", None)
             if priorities is not None:
-                buf_state = buffer.update_priorities(
-                    buf_state, batch["indices"], priorities)
+                with jax.named_scope("replay.update_priorities"):
+                    buf_state = buffer.update_priorities(
+                        buf_state, batch["indices"], priorities)
             return (params, opt_state, buf_state), metrics
 
         (params, opt_state, buf_state), metrics = jax.lax.scan(

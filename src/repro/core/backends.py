@@ -33,13 +33,13 @@ holds (thread pools, worker processes, shared memory) and is idempotent.
 from __future__ import annotations
 
 import dataclasses
-import time
 from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Callable, List, Optional, Protocol, Sequence
 
 import jax
 
 from repro import registry
+from repro.core import timing
 from repro.data import trajectory
 
 
@@ -90,16 +90,19 @@ class BackendCloseMixin:
         self.close()
 
 
-def timed_rollout(rollout: Callable, params: Any, carry: Any):
-    """Run one jitted rollout to completion, returning (carry', traj, dt)."""
-    t0 = time.perf_counter()
-    carry, traj = rollout(params, carry)
-    traj = jax.block_until_ready(traj)
-    return carry, traj, time.perf_counter() - t0
+def timed_rollout(rollout: Callable, params: Any, carry: Any,
+                  sampler: int = 0):
+    """Run one jitted rollout to completion as the ``samplers.rollout``
+    span, returning (carry', traj, dt)."""
+    with timing.span("samplers.rollout", sampler=sampler) as span:
+        carry, traj = rollout(params, carry)
+        traj = jax.block_until_ready(traj)
+    return carry, traj, span.seconds
 
 
 def merge_trajs(trajs: Sequence[Any]) -> Any:
-    return trajectory.merge(list(trajs)) if len(trajs) > 1 else trajs[0]
+    with timing.span("samplers.merge"):
+        return trajectory.merge(list(trajs)) if len(trajs) > 1 else trajs[0]
 
 
 # ================================================================== inline
@@ -115,7 +118,7 @@ class InlineBackend(BackendCloseMixin):
         trajs, times = [], []
         for i in range(self.num_samplers):
             self.carries[i], traj, dt = timed_rollout(
-                self.rollout, params, self.carries[i])
+                self.rollout, params, self.carries[i], i)
             trajs.append(traj)
             times.append(dt)
         merged = merge_trajs(trajs)
@@ -138,11 +141,12 @@ class ThreadedBackend(BackendCloseMixin):
 
     def _one(self, i: int, params):
         self.carries[i], traj, dt = timed_rollout(
-            self.rollout, params, self.carries[i])
+            self.rollout, params, self.carries[i], i)
         return traj, dt
 
     def collect(self, params):
-        futures = [self._pool.submit(self._one, i, params)
+        one = timing.bind(self._one)       # spans land in the caller's record
+        futures = [self._pool.submit(one, i, params)
                    for i in range(self.num_samplers)]
         results = [f.result() for f in futures]
         trajs = [r[0] for r in results]

@@ -23,7 +23,6 @@ other backend.
 """
 from __future__ import annotations
 
-import time
 from functools import partial
 from typing import Any, Callable, Dict, List, NamedTuple, Optional
 
@@ -31,6 +30,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import sampler as sampler_mod
+from repro.core import timing
 from repro.core.backends import BackendCloseMixin
 from repro.data import trajectory
 
@@ -98,7 +98,10 @@ class FusedRunner(BackendCloseMixin):
     The fused engine has no host-visible collect/learn boundary — that is
     the point — so ``IterationLog.collect_time``/``collect_time_serial``
     are 0.0 and ``learn_time`` carries the whole fused iteration's share
-    of the chunk's wall time (DESIGN.md §2).
+    of the chunk's wall time (DESIGN.md §2). Each chunk is one
+    ``core.timing`` iteration, ``runner.chunk``, whose spans
+    ``runner.dispatch``, ``runner.wait``, ``runner.pull`` and
+    ``runner.log`` sit on the chunk's first ``IterationLog`` only.
 
     ``overlap=True`` trades the single fused dispatch for a
     double-buffered two-dispatch pipeline: collect and learn become
@@ -139,8 +142,6 @@ class FusedRunner(BackendCloseMixin):
         self._loops: Dict[int, Callable] = {}
         self._samples_per_iter = sampler_mod.samples_per_rollout(
             env_carry[1].shape[0], horizon)      # obs is (B, obs_dim)
-        from repro.core.timing import PhaseTimer
-        self.timer = PhaseTimer()
 
     @property
     def params(self):
@@ -202,8 +203,10 @@ class FusedRunner(BackendCloseMixin):
     _OVERLAP_WARMUP = 2         # it 0 pays compilation, it 1 gives learn_ref
 
     def _run_overlapped(self, iterations: int) -> List:
+        """The stepped overlap's schedule and span names over the two
+        donated jits; the first iteration of each call collects first."""
         from repro.core.orchestrator import (
-            IterationLog, OverlapClock, record_log, tree_ready)
+            IterationLog, OverlapClock, tree_ready)
         collect_fn, learn_fn = self._overlap_fns()
         if self._overlap_clock is None:
             self._overlap_clock = OverlapClock()
@@ -211,79 +214,95 @@ class FusedRunner(BackendCloseMixin):
         params, opt_state, env_carry, plane_state = self.state
         done0 = len(self.logs)
 
-        t0 = time.perf_counter()
-        env_carry, traj = collect_fn(params, env_carry)
-        jax.block_until_ready(traj)
-        collect_dur = time.perf_counter() - t0      # prologue collect
-        stale = 0.0
+        def collect(params, env_carry):
+            with timing.span("samplers.collect"), \
+                    timing.span("samplers.rollout", sampler=0) as rollout:
+                env_carry, traj = collect_fn(params, env_carry)
+                jax.block_until_ready(traj)
+            return env_carry, traj, rollout.seconds
 
+        traj = None
         for it in range(iterations):
-            data_dur, data_stale = collect_dur, stale
-            t0 = time.perf_counter()
-            out = learn_fn(params, opt_state, plane_state, traj)
-            traj = None
-            saved = 0.0
-            warm, self._overlap_done = (self._overlap_done,
-                                        self._overlap_done + 1)
-            if warm < self._OVERLAP_WARMUP:
-                # serial: block the learn, then collect with fresh params
-                jax.block_until_ready(out[0])
-                window = time.perf_counter() - t0
-                if warm > 0:        # iteration 0 includes compilation
-                    clock.note_serial(window)
-                params, opt_state, plane_state, metrics = out
-                if it + 1 < iterations:
-                    tc = time.perf_counter()
-                    env_carry, traj = collect_fn(params, env_carry)
-                    jax.block_until_ready(traj)
-                    collect_dur, stale = time.perf_counter() - tc, 0.0
-            else:
-                # pipelined: the collect acts with the pre-update params
-                # while the dispatched learn runs on the learner mesh
-                if it + 1 < iterations:
-                    tc = time.perf_counter()
-                    env_carry, traj = collect_fn(params, env_carry)
-                    jax.block_until_ready(traj)
-                    next_dur = time.perf_counter() - tc
-                    saved = clock.saved(next_dur, tree_ready(out[0]))
-                    collect_dur, stale = next_dur, 1.0
-                params, opt_state, plane_state, metrics = out
-                jax.block_until_ready(params)
-                window = time.perf_counter() - t0
-            record_log(self.logs, self.timer, IterationLog(
-                iteration=done0 + it,
-                collect_time=data_dur,
-                collect_time_serial=data_dur,
-                learn_time=max(0.0, window - saved),
-                mean_return=float(metrics["mean_return"]),
-                samples=self._samples_per_iter,
-                staleness=data_stale,
-                overlap_saved_s=saved,
-            ))
+            with timing.iteration("runner.iteration", done0 + it) as rec:
+                if traj is None:
+                    env_carry, traj, collect_dur = collect(params, env_carry)
+                    stale = 0.0
+                data_dur, data_stale = collect_dur, stale
+                saved = 0.0
+                warm, self._overlap_done = (self._overlap_done,
+                                            self._overlap_done + 1)
+                if warm < self._OVERLAP_WARMUP:
+                    # serial: block the learn, then collect with fresh
+                    # params
+                    with timing.span("learner.step") as step:
+                        out = learn_fn(params, opt_state, plane_state, traj)
+                        traj = None
+                        jax.block_until_ready(out[0])
+                    if warm > 0:    # iteration 0 includes compilation
+                        clock.note_serial(step.seconds)
+                    params, opt_state, plane_state, metrics = out
+                    if it + 1 < iterations:
+                        env_carry, traj, collect_dur = collect(params,
+                                                               env_carry)
+                        stale = 0.0
+                else:
+                    # pipelined: the collect acts with the pre-update
+                    # params while the dispatched learn runs on the
+                    # learner mesh
+                    with timing.span("learner.step") as step:
+                        out = learn_fn(params, opt_state, plane_state, traj)
+                        traj = None
+                        if it + 1 < iterations:
+                            env_carry, traj, next_dur = collect(params,
+                                                                env_carry)
+                            saved = clock.saved(next_dur, tree_ready(out[0]))
+                            collect_dur, stale = next_dur, 1.0
+                        params, opt_state, plane_state, metrics = out
+                        jax.block_until_ready(params)
+                with timing.span("runner.log"):
+                    self.logs.append(IterationLog(
+                        iteration=done0 + it,
+                        collect_time=data_dur,
+                        collect_time_serial=data_dur,
+                        learn_time=max(0.0, step.seconds - saved),
+                        mean_return=float(timing.pull(metrics["mean_return"])),
+                        samples=self._samples_per_iter,
+                        staleness=data_stale,
+                        overlap_saved_s=saved,
+                        spans=rec.spans,
+                        counts=rec.counts,
+                    ))
         self.state = TrainState(params, opt_state, env_carry, plane_state)
         return self.logs
 
     def run(self, iterations: int) -> List:
-        from repro.core.orchestrator import IterationLog, record_log
+        from repro.core.orchestrator import IterationLog
         if self.overlap:
             return self._run_overlapped(iterations)
         done = 0
         while done < iterations:
             c = min(self.chunk or iterations, iterations - done)
             loop = self.loop_for(c)
-            t0 = time.perf_counter()
-            self.state, metrics = loop(self.state)
-            jax.block_until_ready(self.state.params)
-            per_iter = (time.perf_counter() - t0) / c
-            returns = jax.device_get(metrics["mean_return"])
-            for j in range(c):
-                record_log(self.logs, self.timer, IterationLog(
-                    iteration=done + j,
-                    collect_time=0.0,
-                    collect_time_serial=0.0,
-                    learn_time=per_iter,
-                    mean_return=float(returns[j]),
-                    samples=self._samples_per_iter,
-                ))
+            with timing.iteration("runner.chunk", len(self.logs)) as rec:
+                with timing.span("runner.dispatch"):
+                    self.state, metrics = loop(self.state)
+                with timing.span("runner.wait"):
+                    jax.block_until_ready(self.state.params)
+                with timing.span("runner.pull"):
+                    returns = timing.pull(metrics["mean_return"])
+                with timing.span("runner.log"):
+                    per_iter = (rec.spans["runner.dispatch"]
+                                + rec.spans["runner.wait"]) / c
+                    for j in range(c):
+                        self.logs.append(IterationLog(
+                            iteration=done + j,
+                            collect_time=0.0,
+                            collect_time_serial=0.0,
+                            learn_time=per_iter,
+                            mean_return=float(returns[j]),
+                            samples=self._samples_per_iter,
+                            spans=rec.spans if j == 0 else {},
+                            counts=rec.counts if j == 0 else {},
+                        ))
             done += c
         return self.logs

@@ -12,7 +12,12 @@
 
 Both runners assemble their ``IterationLog`` through the same helpers
 (``timed_learn`` + ``assemble_log``) so the collect/learn accounting that
-feeds Figs 4-7 has exactly one definition.
+feeds Figs 4-7 has exactly one definition. Each iteration's phases are
+``core.timing`` spans (``runner.iteration``, ``samplers.collect``,
+``learner.step``, ``runner.log``; the async learner's
+``learner.wait_experience`` and ``learner.publish``): the log's times
+are those spans' durations, and ``IterationLog.spans`` / ``counts`` hold
+the iteration's whole record.
 """
 from __future__ import annotations
 
@@ -30,8 +35,8 @@ from repro.core.backends import (
     merge_trajs,
     timed_rollout,
 )
+from repro.core import timing
 from repro.core.queues import Experience, ExperienceQueue, PolicyStore
-from repro.core.timing import PhaseTimer
 from repro.data import trajectory
 
 
@@ -59,6 +64,11 @@ class IterationLog:
                                   # serial iterations; under overlap,
                                   # learn_time is the *exposed* learn cost
                                   # so collect+learn+saved ~= serial wall)
+    spans: Dict[str, float] = dataclasses.field(default_factory=dict)
+    # seconds per ``core.timing`` span name in this iteration (a fused
+    # chunk's call-level spans sit on its first iteration only)
+    counts: Dict[str, int] = dataclasses.field(default_factory=dict)
+    # ``<counter>@<innermost span>``: ``compiles``, ``host_pulls``
 
     def as_dict(self) -> Dict[str, Any]:
         return dataclasses.asdict(self)
@@ -79,39 +89,46 @@ def _maybe_jit_step(train_step: Optional[Callable]) -> Optional[Callable]:
 
 
 def timed_learn(learn: Callable, params, opt_state, merged):
-    """One jitted learner update, blocked and timed."""
-    t0 = time.perf_counter()
-    params, opt_state, metrics = learn(params, opt_state, merged)
-    jax.block_until_ready(params)
-    return params, opt_state, metrics, time.perf_counter() - t0
+    """One jitted learner update, blocked, as the ``learner.step`` span."""
+    with timing.span("learner.step") as step:
+        params, opt_state, metrics = learn(params, opt_state, merged)
+        jax.block_until_ready(params)
+    return params, opt_state, metrics, step.seconds
 
 
 def timed_train_step(train_step: Callable, params, opt_state, plane_state,
                      merged):
-    """One jitted plane step (observe -> sample -> learn), blocked and
-    timed; buffer state stays device-resident inside ``plane_state``."""
-    t0 = time.perf_counter()
-    params, opt_state, plane_state, metrics = train_step(
-        params, opt_state, plane_state, merged)
-    jax.block_until_ready(params)
-    return params, opt_state, plane_state, metrics, time.perf_counter() - t0
+    """One jitted plane step (observe -> sample -> learn), blocked, as
+    the ``learner.step`` span; buffer state stays device-resident inside
+    ``plane_state``."""
+    with timing.span("learner.step") as step:
+        params, opt_state, plane_state, metrics = train_step(
+            params, opt_state, plane_state, merged)
+        jax.block_until_ready(params)
+    return params, opt_state, plane_state, metrics, step.seconds
 
 
 def assemble_log(iteration: int, per_sampler_seconds: Sequence[float],
                  learn_time: float, merged, samples: Optional[int] = None,
+                 *, record: timing.Record,
                  staleness: float = 0.0,
                  queue_drops: int = 0,
                  worker_utilization: float = 1.0,
                  respawns: int = 0,
                  active_workers: int = 0,
                  overlap_saved_s: float = 0.0) -> IterationLog:
-    """The single definition of per-iteration accounting (sync + async)."""
+    """The single definition of per-iteration accounting (sync + async).
+    ``per_sampler_seconds`` are the consumed collect's ``samplers.rollout``
+    spans (a process worker's own clock), ``learn_time`` the
+    ``learner.step`` span (its exposed part under overlap); ``record`` is
+    the iteration's, still open: the log holds its dicts, which the
+    enclosing spans complete as they close."""
     return IterationLog(
         iteration=iteration,
         collect_time=max(per_sampler_seconds),
         collect_time_serial=sum(per_sampler_seconds),
         learn_time=learn_time,
-        mean_return=float(trajectory.episode_returns(merged)),
+        mean_return=float(timing.pull(trajectory.episode_returns(merged))),
         samples=(samples if samples is not None
                  else trajectory.num_samples(merged)),
         staleness=staleness,
@@ -120,6 +137,8 @@ def assemble_log(iteration: int, per_sampler_seconds: Sequence[float],
         respawns=respawns,
         active_workers=active_workers,
         overlap_saved_s=overlap_saved_s,
+        spans=record.spans,
+        counts=record.counts,
     )
 
 
@@ -160,13 +179,6 @@ class OverlapClock:
             return collect_s
         ref = self.learn_ref if self.learn_ref is not None else collect_s
         return min(ref, collect_s)
-
-
-def record_log(logs: List[IterationLog], timer: PhaseTimer,
-               log: IterationLog) -> None:
-    logs.append(log)
-    timer.add("collect", log.collect_time)
-    timer.add("learn", log.learn_time)
 
 
 # ================================================================== sync
@@ -236,7 +248,6 @@ class SyncRunner(BackendCloseMixin):
         self._overlap_done = 0            # pipeline-lifetime iteration
         #                                   count: warmup is paid once per
         #                                   runner, not once per run() call
-        self.timer = PhaseTimer()
         self.logs: List[IterationLog] = []
         self.metrics: Dict[str, Any] = {}  # last learner step's metrics
         #                                    (loss terms), device-resident
@@ -254,26 +265,36 @@ class SyncRunner(BackendCloseMixin):
         return (self._collect_params if self._collect_params is not None
                 else self.params)
 
+    def _collect(self):
+        with timing.span("samplers.collect"):
+            return self.backend.collect(self._rollout_params())
+
+    def _log(self, it: int, stats, learn_time: float, merged,
+             record: timing.Record, **fields) -> None:
+        with timing.span("runner.log"):
+            self.logs.append(assemble_log(
+                it, stats.per_sampler_seconds, learn_time, merged,
+                stats.samples, respawns=stats.respawns,
+                active_workers=stats.active_workers, record=record,
+                **fields))
+
     def run(self, iterations: int) -> List[IterationLog]:
         if self.overlap:
             return self._run_overlapped(iterations)
         for it in range(iterations):
-            merged, stats = self.backend.collect(self._rollout_params())
-            if self._train_step is not None:
-                (self.params, self.opt_state, self.plane_state, self.metrics,
-                 learn_time) = timed_train_step(
-                     self._train_step, self.params, self.opt_state,
-                     self.plane_state, merged)
-            else:
-                (self.params, self.opt_state, self.metrics,
-                 learn_time) = timed_learn(
-                    self.learn, self.params, self.opt_state, merged)
-            self._pin()
-            record_log(self.logs, self.timer,
-                       assemble_log(it, stats.per_sampler_seconds,
-                                    learn_time, merged, stats.samples,
-                                    respawns=stats.respawns,
-                                    active_workers=stats.active_workers))
+            with timing.iteration("runner.iteration", len(self.logs)) as rec:
+                merged, stats = self._collect()
+                if self._train_step is not None:
+                    (self.params, self.opt_state, self.plane_state,
+                     self.metrics, learn_time) = timed_train_step(
+                         self._train_step, self.params, self.opt_state,
+                         self.plane_state, merged)
+                else:
+                    (self.params, self.opt_state, self.metrics,
+                     learn_time) = timed_learn(
+                        self.learn, self.params, self.opt_state, merged)
+                self._pin()
+                self._log(it, stats, learn_time, merged, rec)
         return self.logs
 
     # ----------------------------------------------------------- overlap
@@ -291,58 +312,56 @@ class SyncRunner(BackendCloseMixin):
         clock = self._overlap_clock
         pending = None          # (merged, stats, staleness) pre-collected
         for it in range(iterations):
-            if pending is None:
-                merged, stats = self.backend.collect(self._rollout_params())
-                stale = 0.0
-            else:
-                merged, stats, stale = pending
-                pending = None
-            warm, self._overlap_done = (self._overlap_done,
-                                        self._overlap_done + 1)
-            if warm < self._OVERLAP_WARMUP:
-                (self.params, self.opt_state, self.plane_state, self.metrics,
-                 learn_time) = timed_train_step(
-                     self._train_step, self.params, self.opt_state,
-                     self.plane_state, merged)
-                if warm > 0:    # iteration 0 includes compilation
-                    clock.note_serial(learn_time)
-                self._pin()
-                record_log(self.logs, self.timer,
-                           assemble_log(it, stats.per_sampler_seconds,
-                                        learn_time, merged, stats.samples,
-                                        staleness=stale,
-                                        respawns=stats.respawns,
-                                        active_workers=stats.active_workers))
-                continue
-            # dispatch the learn; do NOT block — self.params still refers
-            # to the pre-update arrays, which is exactly the one-version-
-            # stale policy the pipelined collect is specified to act with
-            t0 = time.perf_counter()
+            with timing.iteration("runner.iteration", len(self.logs)) as rec:
+                pending = self._overlapped_iteration(it, iterations, clock,
+                                                     pending, rec)
+        return self.logs
+
+    def _overlapped_iteration(self, it: int, iterations: int,
+                              clock: OverlapClock, pending,
+                              rec: timing.Record):
+        """One iteration of the pipeline; returns the next ``pending``."""
+        if pending is None:
+            merged, stats = self._collect()
+            stale = 0.0
+        else:
+            merged, stats, stale = pending
+            pending = None
+        warm, self._overlap_done = (self._overlap_done,
+                                    self._overlap_done + 1)
+        if warm < self._OVERLAP_WARMUP:
+            (self.params, self.opt_state, self.plane_state, self.metrics,
+             learn_time) = timed_train_step(
+                 self._train_step, self.params, self.opt_state,
+                 self.plane_state, merged)
+            if warm > 0:    # iteration 0 includes compilation
+                clock.note_serial(learn_time)
+            self._pin()
+            self._log(it, stats, learn_time, merged, rec, staleness=stale)
+            return None
+        # dispatch the learn; do NOT block — self.params still refers
+        # to the pre-update arrays, which is exactly the one-version-
+        # stale policy the pipelined collect is specified to act with
+        saved = 0.0
+        with timing.span("learner.step") as step:
             out = self._train_step(self.params, self.opt_state,
                                    self.plane_state, merged)
-            saved = 0.0
             if it + 1 < iterations:
                 # _rollout_params() was last pinned *before* this learn
                 # dispatched — the one-version-stale policy by construction
-                nxt, nstats = self.backend.collect(self._rollout_params())
+                nxt, nstats = self._collect()
                 saved = clock.saved(max(nstats.per_sampler_seconds),
                                     tree_ready(out[0]))
                 pending = (nxt, nstats, 1.0)
             self.params, self.opt_state, self.plane_state, self.metrics = out
             jax.block_until_ready(self.params)
-            window = time.perf_counter() - t0
-            self._pin()
-            # window spans the overlapped collect; subtracting the hidden
-            # portion leaves the *exposed* learn cost, so per iteration
-            # collect_time + learn_time + overlap_saved_s ~= serial wall
-            record_log(self.logs, self.timer,
-                       assemble_log(it, stats.per_sampler_seconds,
-                                    max(0.0, window - saved), merged,
-                                    stats.samples, staleness=stale,
-                                    respawns=stats.respawns,
-                                    active_workers=stats.active_workers,
-                                    overlap_saved_s=saved))
-        return self.logs
+        self._pin()
+        # the step spans the overlapped collect; subtracting the hidden
+        # portion leaves the *exposed* learn cost, so per iteration
+        # collect_time + learn_time + overlap_saved_s ~= serial wall
+        self._log(it, stats, max(0.0, step.seconds - saved), merged, rec,
+                  staleness=stale, overlap_saved_s=saved)
+        return pending
 
     def close(self) -> None:
         """Release the backend (thread pools, worker processes, shm)."""
@@ -409,7 +428,6 @@ class AsyncOrchestrator(BackendCloseMixin):
         self.carries = carries
         self.num_samplers = num_samplers
         self.min_batches = min_batches_per_update
-        self.timer = PhaseTimer()
         self.logs: List[IterationLog] = []
         self._stop = threading.Event()
 
@@ -434,7 +452,7 @@ class AsyncOrchestrator(BackendCloseMixin):
         while not self._stop.is_set():
             params, version = self.store.read()
             self.carries[i], traj, dt = timed_rollout(
-                self.rollout, params, self.carries[i])
+                self.rollout, params, self.carries[i], i)
             # on overflow the experience is dropped and counted
             # (ExperienceQueue.drop_count -> IterationLog.queue_drops)
             if (not self.expq.put(Experience(traj, version, i, dt),
@@ -442,44 +460,51 @@ class AsyncOrchestrator(BackendCloseMixin):
                     and self._stop.is_set()):
                 return
 
+    def _learn_merged(self, merged):
+        """The learner's update of the store's params on ``merged``, as
+        ``learner.step``; returns ``(params, learn_time)``."""
+        params, _ = self.store.read()
+        if self._train_step is not None:
+            (params, self.opt_state, self.plane_state, _,
+             learn_time) = timed_train_step(
+                 self._train_step, params, self.opt_state,
+                 self.plane_state, merged)
+        else:
+            params, self.opt_state, _, learn_time = timed_learn(
+                self.learn, params, self.opt_state, merged)
+        return params, learn_time
+
     def _learner_loop(self, updates: int) -> None:
         import queue as _q
         for it in range(updates):
-            exps: List[Experience] = []
-            t_wait0 = time.perf_counter()
-            while len(exps) < self.min_batches and not self._stop.is_set():
-                try:
-                    exps.append(self.expq.get(self.store.version,
-                                              timeout=1.0))
-                except _q.Empty:
-                    continue
-            if self._stop.is_set() and not exps:
-                return
-            wait = time.perf_counter() - t_wait0
-            if self.staleness is not None and self.staleness.enabled:
-                import jax.numpy as jnp
-                trajs = [self._attach_gap(
-                    e.traj, self.store.version - e.policy_version, jnp)
-                    for e in exps]
-            else:
-                trajs = [e.traj for e in exps]
-            merged = merge_trajs(trajs)
-            params, _ = self.store.read()
-            if self._train_step is not None:
-                (params, self.opt_state, self.plane_state, _,
-                 learn_time) = timed_train_step(
-                     self._train_step, params, self.opt_state,
-                     self.plane_state, merged)
-            else:
-                params, self.opt_state, _, learn_time = timed_learn(
-                    self.learn, params, self.opt_state, merged)
-            self.store.publish(params)
-            record_log(self.logs, self.timer,
-                       assemble_log(it, [e.collect_seconds for e in exps],
-                                    learn_time, merged,
-                                    staleness=self.expq.mean_staleness(),
-                                    queue_drops=self.expq.drop_count))
-            self.timer.add("collect_wait", wait)
+            with timing.iteration("runner.iteration", len(self.logs)) as rec:
+                exps: List[Experience] = []
+                with timing.span("learner.wait_experience"):
+                    while (len(exps) < self.min_batches
+                           and not self._stop.is_set()):
+                        try:
+                            exps.append(self.expq.get(self.store.version,
+                                                      timeout=1.0))
+                        except _q.Empty:
+                            continue
+                if self._stop.is_set() and not exps:
+                    return
+                if self.staleness is not None and self.staleness.enabled:
+                    import jax.numpy as jnp
+                    trajs = [self._attach_gap(
+                        e.traj, self.store.version - e.policy_version, jnp)
+                        for e in exps]
+                else:
+                    trajs = [e.traj for e in exps]
+                merged = merge_trajs(trajs)
+                params, learn_time = self._learn_merged(merged)
+                with timing.span("learner.publish"):
+                    self.store.publish(params)
+                with timing.span("runner.log"):
+                    self.logs.append(assemble_log(
+                        it, [e.collect_seconds for e in exps], learn_time,
+                        merged, staleness=self.expq.mean_staleness(),
+                        queue_drops=self.expq.drop_count, record=rec))
 
     # ------------------------------------------------- process-pool learner
     def _learner_loop_pool(self, updates: int, deadline: float) -> None:
@@ -500,52 +525,46 @@ class AsyncOrchestrator(BackendCloseMixin):
         source = self.supervisor if self.supervisor is not None else self.pool
         stale_on = self.staleness is not None and self.staleness.enabled
         for it in range(updates):
-            exps, gaps = [], []
-            collect_s = loop_s = 0.0         # this iteration's window only
-            t_wait0 = time.perf_counter()
-            while len(exps) < self.min_batches and not self._stop.is_set():
-                if time.monotonic() > deadline:
+            with timing.iteration("runner.iteration", len(self.logs)) as rec:
+                exps, gaps = [], []
+                collect_s = loop_s = 0.0     # this iteration's window only
+                with timing.span("learner.wait_experience"):
+                    while (len(exps) < self.min_batches
+                           and not self._stop.is_set()):
+                        if time.monotonic() > deadline:
+                            return
+                        got = source.next_experience(timeout=1.0)
+                        if got is None:
+                            continue
+                        exp, loop_dt = got
+                        exps.append(exp)
+                        collect_s += exp.collect_seconds
+                        loop_s += loop_dt
+                        gaps.append(max(0, self.pool.version
+                                        - exp.policy_version))
+                if self._stop.is_set() and not exps:
                     return
-                got = source.next_experience(timeout=1.0)
-                if got is None:
-                    continue
-                exp, loop_dt = got
-                exps.append(exp)
-                collect_s += exp.collect_seconds
-                loop_s += loop_dt
-                gaps.append(max(0, self.pool.version - exp.policy_version))
-            if self._stop.is_set() and not exps:
-                return
-            wait = time.perf_counter() - t_wait0
-            trajs = [e.traj for e in exps]
-            if stale_on:
-                trajs = [self._attach_gap(t, g, _np)
-                         for t, g in zip(trajs, gaps)]
-            merged = merge_trajs(
-                [{k: jax.numpy.asarray(v) for k, v in t.items()}
-                 for t in trajs])
-            params, _ = self.store.read()
-            if self._train_step is not None:
-                (params, self.opt_state, self.plane_state, _,
-                 learn_time) = timed_train_step(
-                     self._train_step, params, self.opt_state,
-                     self.plane_state, merged)
-            else:
-                params, self.opt_state, _, learn_time = timed_learn(
-                    self.learn, params, self.opt_state, merged)
-            self.store.publish(params)
-            self.pool.publish(params)
-            util = collect_s / loop_s if loop_s > 0 else 1.0
-            record_log(self.logs, self.timer,
-                       assemble_log(it0 + it,
-                                    [e.collect_seconds for e in exps],
-                                    learn_time, merged,
-                                    staleness=float(sum(gaps) / len(gaps)),
-                                    worker_utilization=util,
-                                    respawns=(self.supervisor.respawns
-                                              if self.supervisor else 0),
-                                    active_workers=self.pool.num_workers))
-            self.timer.add("collect_wait", wait)
+                trajs = [e.traj for e in exps]
+                if stale_on:
+                    trajs = [self._attach_gap(t, g, _np)
+                             for t, g in zip(trajs, gaps)]
+                merged = merge_trajs(
+                    [{k: jax.numpy.asarray(v) for k, v in t.items()}
+                     for t in trajs])
+                params, learn_time = self._learn_merged(merged)
+                with timing.span("learner.publish"):
+                    self.store.publish(params)
+                    self.pool.publish(params)
+                util = collect_s / loop_s if loop_s > 0 else 1.0
+                with timing.span("runner.log"):
+                    self.logs.append(assemble_log(
+                        it0 + it, [e.collect_seconds for e in exps],
+                        learn_time, merged,
+                        staleness=float(sum(gaps) / len(gaps)),
+                        worker_utilization=util,
+                        respawns=(self.supervisor.respawns
+                                  if self.supervisor else 0),
+                        active_workers=self.pool.num_workers, record=rec))
             if self.supervisor is not None:
                 self.supervisor.autoscale(util)
 

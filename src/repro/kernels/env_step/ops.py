@@ -16,12 +16,14 @@ candidates in the same layout — and selects the implementation through
 
 The kernels are float32-only (the envs' default dtype); experiments
 running an env under another dtype fall back to the ref path so the
-dispatcher never changes numerics, only scheduling.
+dispatcher never changes numerics, only scheduling. Either path runs
+under the ``envs.step`` scope, so the device trace names the op.
 """
 from __future__ import annotations
 
 from typing import Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 
 from repro.kernels import select
@@ -43,9 +45,10 @@ def env_step(name: str, state, actions, reset_state, reset_obs, *,
         raise KeyError(f"no env_step kernels for env {name!r}; "
                        f"choose from {sorted(ref.STEP_BATCH_REF)}")
     impl_name, interpret = select.resolve(impl, "env_step.step")
-    if impl_name == "pallas" and jnp.dtype(dtype) == jnp.float32:
-        return env_step_pallas.STEP_BATCH_PALLAS[name](
-            state, actions, reset_state, reset_obs,
-            interpret=interpret, **params)
-    return ref.STEP_BATCH_REF[name](
-        state, actions, reset_state, reset_obs, dtype=dtype, **params)
+    with jax.named_scope("envs.step"):
+        if impl_name == "pallas" and jnp.dtype(dtype) == jnp.float32:
+            return env_step_pallas.STEP_BATCH_PALLAS[name](
+                state, actions, reset_state, reset_obs,
+                interpret=interpret, **params)
+        return ref.STEP_BATCH_REF[name](
+            state, actions, reset_state, reset_obs, dtype=dtype, **params)

@@ -6,12 +6,14 @@ implementation through ``kernels.select`` (``impl=`` overrides per call).
 The ref path forwards the original arrays untouched, so the CPU-default
 resolution is the historical ``algos/gae.py`` recurrence bit for bit;
 the pallas path flattens the batch dims to one lane axis for the kernel
-and restores the caller's shape on the way out.
+and restores the caller's shape on the way out. ``gae`` runs under the
+``learner.gae`` scope on either path, so the device trace names the op.
 """
 from __future__ import annotations
 
 from typing import Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 
 from repro.kernels import select
@@ -33,14 +35,15 @@ def gae(rewards: jnp.ndarray, values: jnp.ndarray, dones: jnp.ndarray,
         ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Advantages + returns; see ``ref.gae_ref`` for semantics."""
     name, interpret = select.resolve(impl, "gae.gae")
-    if name == "ref":
-        return gae_ref(rewards, values, dones, last_value, gamma, lam)
-    nonterm = 1.0 - dones.astype(jnp.float32)
-    adv, ret = gae_pallas(
-        _flatten_batch(rewards), _flatten_batch(values),
-        _flatten_batch(nonterm), last_value.reshape(-1),
-        gamma=gamma, lam=lam, interpret=interpret)
-    return adv.reshape(rewards.shape), ret.reshape(rewards.shape)
+    with jax.named_scope("learner.gae"):
+        if name == "ref":
+            return gae_ref(rewards, values, dones, last_value, gamma, lam)
+        nonterm = 1.0 - dones.astype(jnp.float32)
+        adv, ret = gae_pallas(
+            _flatten_batch(rewards), _flatten_batch(values),
+            _flatten_batch(nonterm), last_value.reshape(-1),
+            gamma=gamma, lam=lam, interpret=interpret)
+        return adv.reshape(rewards.shape), ret.reshape(rewards.shape)
 
 
 def discounted_returns(rewards: jnp.ndarray, dones: jnp.ndarray,

@@ -5,11 +5,14 @@ leaf is ``(capacity, ...)``. The pallas path flattens trailing dims to
 one feature axis per leaf and launches one fused kernel per leaf; the
 ref path forwards to the oracle scatter/gather untouched, keeping the
 CPU-default resolution bitwise-identical to the pre-plane behavior.
+The ops run under the ``replay.insert`` / ``replay.gather`` scopes on
+either path, so the device trace names them.
 """
 from __future__ import annotations
 
 from typing import Dict, Optional
 
+import jax
 import jax.numpy as jnp
 
 from repro.kernels import select
@@ -29,25 +32,27 @@ def ring_insert(storage: Dict[str, jnp.ndarray],
                 impl: Optional[str] = None) -> Dict[str, jnp.ndarray]:
     """Scatter-insert (N, ...) transitions at the ring head (wraps)."""
     name, interpret = select.resolve(impl, "replay_ring.insert")
-    if name == "ref":
-        return ring_insert_ref(storage, batch, start)
-    return {
-        k: ring_insert_pallas(_as2d(storage[k]),
-                              _as2d(batch[k]).astype(storage[k].dtype),
-                              start, interpret=interpret)
-        .reshape(storage[k].shape)
-        for k in storage
-    }
+    with jax.named_scope("replay.insert"):
+        if name == "ref":
+            return ring_insert_ref(storage, batch, start)
+        return {
+            k: ring_insert_pallas(_as2d(storage[k]),
+                                  _as2d(batch[k]).astype(storage[k].dtype),
+                                  start, interpret=interpret)
+            .reshape(storage[k].shape)
+            for k in storage
+        }
 
 
 def ring_gather(storage: Dict[str, jnp.ndarray], idx: jnp.ndarray, *,
                 impl: Optional[str] = None) -> Dict[str, jnp.ndarray]:
     """Draw the rows at ``idx`` (B,) from every leaf."""
     name, interpret = select.resolve(impl, "replay_ring.gather")
-    if name == "ref":
-        return ring_gather_ref(storage, idx)
-    return {
-        k: ring_gather_pallas(_as2d(v), idx, interpret=interpret)
-        .reshape((idx.shape[0],) + v.shape[1:])
-        for k, v in storage.items()
-    }
+    with jax.named_scope("replay.gather"):
+        if name == "ref":
+            return ring_gather_ref(storage, idx)
+        return {
+            k: ring_gather_pallas(_as2d(v), idx, interpret=interpret)
+            .reshape((idx.shape[0],) + v.shape[1:])
+            for k, v in storage.items()
+        }

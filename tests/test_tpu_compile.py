@@ -14,6 +14,8 @@ The topology is described inside a fixture, never at import: only one
 process may load the TPU library at a time, and every test worker
 imports this file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -22,9 +24,15 @@ from jax.sharding import SingleDeviceSharding
 from repro import registry
 from repro.kernels import select
 from repro.kernels.env_step import env_step_pallas
+from repro.kernels.env_step import ops as env_step_ops
 from repro.kernels.env_step import ref as env_ref
+from repro.kernels.gae import ops as gae_ops
 from repro.kernels.gae.gae_pallas import gae_pallas
+from repro.kernels.replay_ring import ops as ring_ops
 from repro.kernels.replay_ring.replay_ring_pallas import ring_gather_pallas
+from repro.kernels.sum_tree import ops as tree_ops
+from repro.kernels.sum_tree.ref import SumTree
+from repro.kernels.sum_tree.sum_tree_pallas import level_sizes
 from repro.kernels.sum_tree.sum_tree_pallas import sumtree_find_pallas
 
 CAPACITY = 1 << 17          # PrioritizedBuffer(capacity=100_000)
@@ -121,3 +129,73 @@ def test_tpu_refused_kernels_resolve_to_ref(monkeypatch, kernel):
     assert select.resolve("auto", "sum_tree.find") == ("pallas", False)
     assert select.resolve("pallas", "replay_ring.gather") == ("pallas",
                                                               False)
+
+
+# ------------------------------------------- the dispatchers' op scopes
+# The benchmark cells' widths: GAE over the fused cell's (16, 4096) and
+# the sync cell's (1000, 20); the cheetah step at the fused cell's 4096
+# and the SAC cell's 64 envs; the SAC cell's 2^20-row prioritized replay
+# (the ring's fields as ``data/replay.py`` stores them) and 256-row draw.
+SAC_CAPACITY = 1 << 20
+SAC_RING = {"obs": (OBS_DIM,), "actions": (6,), "rewards": (),
+            "next_obs": (OBS_DIM,), "discounts": ()}
+OP_NAME = re.compile(r'op_name="([^"]+)"')
+
+
+@pytest.fixture
+def pallas(monkeypatch):
+    """Every dispatcher takes the compiled Pallas path, as on the chip."""
+    monkeypatch.setattr(select, "resolve",
+                        lambda impl=None, kernel=None: ("pallas", False))
+
+
+def _kernel_scopes(text: str) -> list:
+    """The ``op_name`` path of each Pallas custom call in ``text``."""
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert calls, "no Pallas kernel in the program"
+    return [OP_NAME.search(line).group(1).split("/") for line in calls]
+
+
+@pytest.mark.parametrize("shape", [(16, 4096), (1000, 20)])
+def test_gae_dispatcher_names_its_kernel(one_chip, pallas, shape):
+    tb = _spec(one_chip, shape)
+    text = _compile_text(lambda r, v, d, lv: gae_ops.gae(r, v, d, lv),
+                         tb, tb, tb, _spec(one_chip, shape[1:]))
+    assert all("learner.gae" in path for path in _kernel_scopes(text))
+
+
+@pytest.mark.parametrize("batch", [4096, 64])
+def test_env_step_dispatcher_names_its_kernel(one_chip, pallas, batch):
+    env = registry.make("env", "cheetah")
+    keys = jax.random.split(jax.random.PRNGKey(0), batch)
+    state, obs = jax.eval_shape(jax.vmap(env.reset), keys)
+
+    def placed(tree):
+        return jax.tree.map(lambda s: _spec(one_chip, s.shape, s.dtype), tree)
+
+    text = _compile_text(
+        lambda s, a, rs, ro: env_step_ops.env_step(
+            "cheetah", s, a, rs, ro, max_episode_steps=env.max_episode_steps,
+            reward_scale=1.0, ctrl_cost=0.1),
+        placed(state), _spec(one_chip, (batch, env.act_dim)),
+        placed(state), placed(obs))
+    assert all("envs.step" in path for path in _kernel_scopes(text))
+
+
+def test_sumtree_find_dispatcher_names_its_kernel(one_chip, pallas):
+    tree = SumTree(tuple(_spec(one_chip, (n,))
+                         for n in level_sizes(SAC_CAPACITY)))
+    text = _compile_text(tree_ops.sumtree_find_batch, tree,
+                         _spec(one_chip, (MINIBATCH,)))
+    assert all("replay.find" in path for path in _kernel_scopes(text))
+
+
+def test_ring_gather_dispatcher_names_its_kernels(one_chip, pallas):
+    storage = {k: _spec(one_chip, (SAC_CAPACITY,) + dims)
+               for k, dims in SAC_RING.items()}
+    text = _compile_text(ring_ops.ring_gather, storage,
+                         _spec(one_chip, (MINIBATCH,), jnp.int32))
+    scopes = _kernel_scopes(text)
+    assert len(scopes) == len(SAC_RING)          # one launch per field
+    assert all("replay.gather" in path for path in scopes)
