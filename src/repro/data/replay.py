@@ -3,8 +3,10 @@
 The scatter-insert and minibatch-gather hot paths dispatch through the
 kernel plane (``repro.kernels.replay_ring``): with the ref selection —
 the CPU default — they are the historical XLA scatter/gather bit for
-bit; on TPU (``--kernels auto``/``pallas``) each becomes one fused
-Pallas launch per storage leaf.
+bit. On TPU (``--kernels auto``) the insert stays XLA's scatter
+(``kernels.select.TPU_REFUSED``) and the gather is one fused Pallas
+launch per ``(capacity, width)`` leaf, with the other leaves (rewards,
+discounts) gathered in place by XLA.
 """
 from __future__ import annotations
 

@@ -1,9 +1,14 @@
 """Dispatching public ops for the replay-ring kernel family.
 
 Dict-of-leaves layout, exactly as ``data/replay.py`` stores it: each
-leaf is ``(capacity, ...)``. The pallas path flattens trailing dims to
-one feature axis per leaf and launches one fused kernel per leaf; the
-ref path forwards to the oracle scatter/gather untouched, keeping the
+leaf is ``(capacity, ...)``. The pallas insert flattens trailing dims to
+one feature axis per leaf and launches one fused kernel per leaf. The
+pallas gather launches its row-block kernel on each ``(capacity, width)``
+leaf only: reshaping any other leaf to that form is a relayout of the
+whole leaf on TPU (a one-wide ``(capacity,)`` field becomes
+``(capacity, 1)`` padded to 128 lanes, 128x its bytes, per draw), so
+those leaves are gathered in place by XLA's own ``v[idx]``. The ref
+path forwards to the oracle scatter/gather untouched, keeping the
 CPU-default resolution bitwise-identical to the pre-plane behavior.
 The ops run under the ``replay.insert`` / ``replay.gather`` scopes on
 either path, so the device trace names them.
@@ -46,13 +51,14 @@ def ring_insert(storage: Dict[str, jnp.ndarray],
 
 def ring_gather(storage: Dict[str, jnp.ndarray], idx: jnp.ndarray, *,
                 impl: Optional[str] = None) -> Dict[str, jnp.ndarray]:
-    """Draw the rows at ``idx`` (B,) from every leaf."""
+    """Draw the rows at ``idx`` (B,) from every leaf: the Pallas kernel
+    on ``(capacity, width)`` leaves, XLA's gather in place on the rest."""
     name, interpret = select.resolve(impl, "replay_ring.gather")
     with jax.named_scope("replay.gather"):
         if name == "ref":
             return ring_gather_ref(storage, idx)
         return {
-            k: ring_gather_pallas(_as2d(v), idx, interpret=interpret)
-            .reshape((idx.shape[0],) + v.shape[1:])
+            k: (ring_gather_pallas(v, idx, interpret=interpret)
+                if v.ndim == 2 else v[idx])
             for k, v in storage.items()
         }

@@ -1,8 +1,9 @@
 """Fused replay-ring Pallas kernels for TPU.
 
-The uniform ring's two hot paths as single kernel launches per storage
-leaf (leaves are 2D ``(capacity, features)`` tiles; the ops layer
-flattens trailing dims):
+The uniform ring's two hot paths as single kernel launches on a 2D
+``(capacity, features)`` leaf. The ops layer flattens trailing dims for
+the insert; the gather runs only on leaves stored in that form, and the
+ops layer hands every other leaf to XLA's gather in place:
 
 * ``ring_insert_pallas`` — scatter-insert N transitions at the write
   head with wraparound, rows streamed through VMEM in one launch instead

@@ -177,13 +177,19 @@ def test_ring_insert_pallas_matches_ref_exactly(cap, n, start):
 
 @pytest.mark.parametrize("cap,B", [(17, 6), (1, 1), (64, 64)])
 def test_ring_gather_pallas_matches_ref_exactly(cap, B):
-    ks = jax.random.split(jax.random.fold_in(KEY, cap * 7 + B), 2)
+    ks = jax.random.split(jax.random.fold_in(KEY, cap * 7 + B), 4)
+    # every stored form: (cap, width) takes the Pallas kernel; rank-1 and
+    # rank-3 leaves take XLA's gather in place
     storage = {"obs": jax.random.normal(ks[0], (cap, 2, 2)),
-               "rewards": jax.random.normal(ks[1], (cap,))}
+               "rewards": jax.random.normal(ks[1], (cap,)),
+               "actions": jax.random.normal(ks[2], (cap, 3)),
+               "steps": jax.random.randint(ks[3], (cap,), -50, 50)}
     idx = jax.random.randint(jax.random.fold_in(KEY, B), (B,), 0, cap)
     g_r = ring_k.ring_gather(storage, idx, impl="ref")
     g_p = ring_k.ring_gather(storage, idx, impl="pallas")
     assert g_p["obs"].shape == (B, 2, 2)
+    assert g_p["actions"].shape == (B, 3)
+    assert g_p["steps"].dtype == jnp.int32
     assert_trees_equal(g_r, g_p)
 
 

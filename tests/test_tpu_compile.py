@@ -197,5 +197,41 @@ def test_ring_gather_dispatcher_names_its_kernels(one_chip, pallas):
     text = _compile_text(ring_ops.ring_gather, storage,
                          _spec(one_chip, (MINIBATCH,), jnp.int32))
     scopes = _kernel_scopes(text)
-    assert len(scopes) == len(SAC_RING)          # one launch per field
+    # one launch per (capacity, width) field; the one-wide fields go to
+    # XLA's gather in place, under the same scope
+    assert len(scopes) == sum(len(dims) == 1 for dims in SAC_RING.values())
     assert all("replay.gather" in path for path in scopes)
+    gathers = [line for line in text.splitlines()
+               if re.search(r"= f32\[256\]\S* gather\(", line)]
+    assert len(gathers) == sum(not dims for dims in SAC_RING.values())
+    assert all("replay.gather" in OP_NAME.search(line).group(1)
+               for line in gathers)
+
+
+def _while_bodies(text: str) -> str:
+    """The HLO text of every ``while`` loop body in ``text``."""
+    names = set(re.findall(r"\bwhile\(.*\bbody=(%[\w.-]+)", text))
+    assert names, "no while loop in the program"
+    blocks = text.split("\n\n")
+    return "\n".join(b for b in blocks
+                     if any(b.lstrip().startswith(n + " ") for n in names))
+
+
+def test_ring_gather_in_a_loop_never_relays_the_ring(one_chip, pallas):
+    """Draws inside a scan (the SAC update loop) read the ring where it
+    lies: no leaf is reshaped into a padded (capacity, 1) layout, and the
+    loop body copies no ring-sized buffer."""
+    storage = {k: _spec(one_chip, (SAC_CAPACITY,) + dims)
+               for k, dims in SAC_RING.items()}
+
+    def draws(storage, idx):
+        return jax.lax.scan(
+            lambda s, i: (s, ring_ops.ring_gather(s, i)), storage, idx)
+
+    text = _compile_text(draws, storage,
+                         _spec(one_chip, (2, MINIBATCH), jnp.int32))
+    assert not re.search(rf"\bf32\[{SAC_CAPACITY},1\]", text)
+    body_copies = [
+        line for line in _while_bodies(text).splitlines()
+        if re.search(rf"= \w+\[{SAC_CAPACITY}[,\]]\S* copy\(", line)]
+    assert not body_copies, body_copies
