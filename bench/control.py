@@ -10,9 +10,11 @@ does, drives its first calls and compares them with the plain reference
 seeds it also puts the reference itself in the system's place, computed
 in bfloat16 (the configuration states float32), and with each fault the
 cell can have planted in it: half of each minibatch left out (the mean
-taken over the rest). A step
-that leaves the state unchanged needs no run: it reads 1 on the gradient
-and parameter-change numbers by their definition.
+taken over the rest). A reference that follows the program follows
+whatever stands in the program's place, as a run of the cell does
+(``harness.run_reference``). A step that leaves the state unchanged needs
+no run: it reads 1 on the gradient and parameter-change numbers by their
+definition.
 
 One JSON line per reading; the cell's own size, on the chips it asks for.
 """
@@ -31,19 +33,26 @@ sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 from bench import harness  # noqa: E402
 
 
-def program_reading(cell, seed: int, ref: dict) -> dict:
+def reading(cell, reference, seed: int, observed: dict) -> dict:
+    """``observed`` (the program's, or what stands in its place) against
+    the reference."""
+    from bench import reflib
+    return reflib.compare(observed, harness.run_reference(
+        reference, cell.config, cell.traffic, seed, observed))
+
+
+def program_observed(cell, reference, seed: int) -> dict:
     from repro import experiment
-    from bench import reflib, spec
+    from bench import spec
     cfg, traffic = cell.config, cell.traffic
     runner = experiment.build(spec.experiment_spec(cfg, traffic, seed))
     if "replay_fill" in traffic:
         harness.fill_replay(runner, cfg, traffic, seed)
     caller = harness.Caller(runner, traffic, cfg["loss_key"])
-    observed = harness.check_steps(caller, cell.reference(),
-                                   harness.CHECK_STEPS)
+    observed = harness.check_steps(caller, reference, harness.CHECK_STEPS)
     del caller, runner
     gc.collect()
-    return reflib.compare(observed, ref)
+    return observed
 
 
 def main(argv=None) -> int:
@@ -56,7 +65,6 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     import jax
     import jax.numpy as jnp
-    from bench import reflib
     cell = harness.Cell(harness.load_json(ROOT / "BENCHMARK.json"),
                         args.workload, ROOT)
     if jax.devices()[0].platform != "tpu" or len(jax.devices()) < cell.chips:
@@ -71,17 +79,16 @@ def main(argv=None) -> int:
         for i in range(args.seeds):
             seed = args.first_seed + i
             t0 = time.perf_counter()
-            ref = reference.run(cfg, traffic, seed, harness.CHECK_STEPS)
-            rows = [("program", program_reading(cell, seed, ref))]
+            stand_ins = {"program": program_observed(cell, reference, seed)}
             if i < args.controls:
-                rows.append(("control_bf16", reflib.compare(reference.run(
+                stand_ins["control_bf16"] = reference.run(
                     cfg, traffic, seed, harness.CHECK_STEPS,
-                    dtype=jnp.bfloat16, precision="default"), ref)))
+                    dtype=jnp.bfloat16, precision="default")
                 for fault in faults:
-                    rows.append((f"fault_{fault}", reflib.compare(
-                        reference.run(cfg, traffic, seed,
-                                      harness.CHECK_STEPS, fault=fault),
-                        ref)))
+                    stand_ins[f"fault_{fault}"] = reference.run(
+                        cfg, traffic, seed, harness.CHECK_STEPS, fault=fault)
+            rows = [(kind, reading(cell, reference, seed, observed))
+                    for kind, observed in stand_ins.items()]
             for kind, numbers in rows:
                 line = json.dumps({"workload": cell.name, "seed": seed,
                                    "kind": kind, **numbers,

@@ -16,8 +16,9 @@ A run:
    filled to capacity), and drives the runner through its first
    ``CHECK_STEPS`` calls, the same calls the window makes. They compile
    every program the window uses, and what they leave behind (losses,
-   Adam's state after the first, the parameters after the last) is what
-   the reference is compared with;
+   Adam's state after the first, the parameters after the last and, for
+   a stepped runtime, each call's trajectory) is what the reference is
+   compared with;
 3. the window: calls the runner until ``--seconds`` have passed, each call
    ending in ``block_until_ready``; ``env_steps_per_s`` is every env-step
    collected and trained on over the whole window. With ``--trace 1`` the
@@ -26,6 +27,36 @@ A run:
 4. reads the device's peak memory, frees the system's state and runs the
    configuration's plain reference from the seed through the same steps,
    then compares (``reflib.compare``) against the cell's limits.
+
+A reference that follows the program. The reference is the configuration's
+file beside its JSON (``bench/configs/<config>.py``); it exports
+``run(cfg, traffic, seed, steps, **kw) -> {"losses", "grad", "change"}``
+and ``program_observables(params, opt_state)``. Where the program draws
+at random (sampled actions, sampled tokens), a reference that draws for
+itself trains on other data as soon as one draw flips, and the comparison
+then measures sampling noise. So a reference module may set
+``FOLLOWS_PROGRAM = True``. In a stepped runtime (``sync``: one collect
+-> learn iteration per call) the harness then keeps a host copy of the
+merged trajectory each check call collected and calls
+``run(cfg, traffic, seed, CHECK_STEPS, program=[traj_1, ..., traj_n])``,
+each ``traj_k`` the dict of time-major arrays the runner's collect
+returned in call ``k``. The rule for what a reference may take from it:
+
+* what the program drew: the sampled actions; for a token policy, the
+  drawn tokens and the prompts;
+* what each step was fed: the observation, or the token context.
+
+It recomputes everything else from those: each env step (next
+observation, reward, done, with the step's env key by the system's key
+rules), each step's behaviour log-prob and value, the advantages, the
+minibatch epochs and the optimizer steps. It never copies a result the
+program computed into its own computation. What it checks step by step
+it returns under ``numbers`` (``{name: gap}``), which ``reflib.compare``
+passes through beside its own numbers and a cell's limits file can name.
+A reference that does not set the attribute, and any cell of the fused
+runtime, is called without ``program``: a fused chunk's trajectory never
+leaves the chunk's program, and keeping it would change the timed
+program.
 
 The last line of standard output is one JSON object: ``correct``,
 ``attempted``, ``failed``, ``metrics``, ``device``, with ``--trace 1``
@@ -146,7 +177,10 @@ class Caller:
     dispatch), or one collect -> learn iteration of a stepped runner.
     The fused runner reports no loss, so its ``loop_for`` is wrapped to
     keep each call's loss array (the program it returns is the system's
-    own, unchanged)."""
+    own, unchanged). A stepped (``sync``) runner's ``_collect`` is
+    wrapped on the instance to keep a host copy of each check call's
+    merged trajectory; ``release`` puts the runner's own method back
+    before the window."""
 
     def __init__(self, runner, traffic: dict, loss_key: str):
         from bench import spec
@@ -155,8 +189,9 @@ class Caller:
         self.env_steps = self.iterations * spec.env_steps_per_iteration(
             traffic)
         self.loss_key = loss_key
-        self.keep_losses = False
+        self.keeping = False
         self._losses: List[Any] = []
+        self.trajectories: List[Any] = []
         if traffic["runtime"] == "fused":
             inner = runner.loop_for
 
@@ -165,12 +200,28 @@ class Caller:
 
                 def kept(state):
                     state, metrics = loop(state)
-                    if self.keep_losses:
+                    if self.keeping:
                         self._losses.append(metrics[loss_key])
                     return state, metrics
                 return kept
 
             runner.loop_for = loop_for
+        elif traffic["runtime"] == "sync":
+            import jax
+            collect = runner._collect
+
+            def kept_collect():
+                merged, stats = collect()
+                if self.keeping:
+                    self.trajectories.append(jax.device_get(merged))
+                return merged, stats
+
+            runner._collect = kept_collect
+
+    def release(self) -> None:
+        """The runner's own ``_collect`` again: the window runs it
+        untouched."""
+        vars(self.runner).pop("_collect", None)
 
     def call(self) -> None:
         self.runner.run(self.iterations)
@@ -178,12 +229,12 @@ class Caller:
     def call_with_loss(self) -> float:
         """One call; the mean of its iterations' losses."""
         import numpy as np
-        self.keep_losses = True
+        self.keeping = True
         self._losses.clear()
         try:
             self.call()
         finally:
-            self.keep_losses = False
+            self.keeping = False
         if self._losses:
             return float(np.mean(np.asarray(self._losses[-1])))
         return float(np.asarray(self.runner.metrics[self.loss_key]))
@@ -192,7 +243,7 @@ class Caller:
 def check_steps(caller: Caller, reference_module, steps: int) -> dict:
     """Drive the first ``steps`` calls; keep the observables the
     reference is compared on (as host arrays, so nothing the program
-    holds is kept)."""
+    holds is kept), then release the caller's hold on the runner."""
     from bench import reflib
     params0 = reflib.leaves(caller.runner.params)
     losses, grad = [], None
@@ -201,10 +252,25 @@ def check_steps(caller: Caller, reference_module, steps: int) -> dict:
         if step == 0:
             _, grad = reference_module.program_observables(
                 caller.runner.params, caller.runner.opt_state)
+    caller.release()
     params, _ = reference_module.program_observables(
         caller.runner.params, caller.runner.opt_state)
-    return {"losses": losses, "grad": grad,
-            "change": reflib.change_norms(params0, params)}
+    observed = {"losses": losses, "grad": grad,
+                "change": reflib.change_norms(params0, params)}
+    if caller.trajectories:
+        observed["trajectories"] = caller.trajectories
+    return observed
+
+
+def run_reference(reference_module, cfg: dict, traffic: dict, seed: int,
+                  observed: dict, **kwargs) -> dict:
+    """The reference over the check calls. One that follows the program
+    (``FOLLOWS_PROGRAM``) is handed the check calls' trajectories where
+    ``observed`` has them; any other is called without them."""
+    if (getattr(reference_module, "FOLLOWS_PROGRAM", False)
+            and observed.get("trajectories")):
+        kwargs["program"] = observed["trajectories"]
+    return reference_module.run(cfg, traffic, seed, CHECK_STEPS, **kwargs)
 
 
 def fill_replay(runner, cfg: dict, traffic: dict, seed: int) -> None:
@@ -355,7 +421,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, *,
     del caller, runner
     gc.collect()
     numbers = reflib.compare(
-        observed, reference.run(cfg, traffic, seed, CHECK_STEPS))
+        observed, run_reference(reference, cfg, traffic, seed, observed))
     checks = {k: {"value": numbers[k], "limit": limit}
               for k, limit in cell.limits.items()}
     attempted = window["iterations"]

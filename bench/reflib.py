@@ -131,6 +131,18 @@ def _leaf_gaps(prog: Dict[str, float], ref: Dict[str, float],
                      for n in names])
 
 
+def scaled_gap(prog, ref) -> float:
+    """The worst ``|p - r|`` over the larger of ``|r|`` and the median
+    ``|r|``. A quantity that passes through zero (a joint angle, a
+    velocity, a value near a sign change) would turn round-off into a
+    huge gap against its own size; against its typical size it reads as
+    round-off, while a gap that matters still reads near 1."""
+    p = np.asarray(prog, np.float64)
+    r = np.asarray(ref, np.float64)
+    scale = np.maximum(np.abs(r), max(float(np.median(np.abs(r))), 1e-30))
+    return float(np.max(np.abs(p - r) / scale))
+
+
 def compare(prog: dict, ref: dict) -> Dict[str, float]:
     """The numbers ``correct`` can be decided on, each a relative gap
     (a cell's limits file names the ones it compares):
@@ -142,7 +154,10 @@ def compare(prog: dict, ref: dict) -> Dict[str, float]:
       leaf's; ``grad_gap_median``: the median leaf's;
     * ``change_gap``, ``change_gap_median``: the same for each leaf's
       change over the steps, over the leaves the reference's gradient
-      moves.
+      moves;
+    * whatever a reference that follows the program checked step by step
+      itself (``ref["numbers"]``; ``harness`` states the rule), passed
+      through as it is.
     """
     losses = [abs(p - r) / max(abs(r), 1e-30)
               for p, r in zip(prog["losses"], ref["losses"])]
@@ -157,4 +172,5 @@ def compare(prog: dict, ref: dict) -> Dict[str, float]:
             "grad_gap": float(grad.max()),
             "grad_gap_median": float(np.median(grad)),
             "change_gap": float(change.max()),
-            "change_gap_median": float(np.median(change))}
+            "change_gap_median": float(np.median(change)),
+            **ref.get("numbers", {})}

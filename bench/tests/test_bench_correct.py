@@ -1,12 +1,13 @@
 """``correct`` on the CPU at a size a test run holds: a sound run of each
 one-chip cell passes its limits, and the control (the reference computed
-in bfloat16 in the system's place) fails them."""
+in bfloat16 in the system's place, followed by a reference that follows
+the program as the program would be) fails them."""
 import jax.numpy as jnp
 import pytest
 
 from tiny_cells import run, tiny_cell
 
-from bench import reflib
+from bench import harness, reflib
 
 ONE_CHIP = ["ppo-fused-b4096", "ppo-sync-n10", "sac256-per-1m"]
 
@@ -28,9 +29,10 @@ def test_control_fails_the_limits(name):
     cell = tiny_cell(name)
     ref = cell.reference()
     seed = 2 ** 31 + 5
-    want = ref.run(cell.config, cell.traffic, seed, 3)
     control = ref.run(cell.config, cell.traffic, seed, 3,
                       dtype=jnp.bfloat16, precision="default")
+    want = harness.run_reference(ref, cell.config, cell.traffic, seed,
+                                 control)
     numbers = reflib.compare(control, want)
     assert any(numbers[k] > limit for k, limit in cell.limits.items()), \
         numbers

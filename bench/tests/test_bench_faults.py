@@ -1,7 +1,11 @@
 """A whole run on the CPU with the timed path broken underneath reports
 ``correct`` false: a step that returns its state unchanged, half of the
 batch left out (the mean taken over the rest). The cells run on one
-chip and exchange nothing between chips."""
+chip and exchange nothing between chips. In the stepped cell, whose
+reference follows the program's draws, also: the behaviour log-prob
+computed with a wrong log-std, one cheetah physics term (the
+neighbour coupling) dropped from the env step, and GAE with gamma and
+lambda swapped."""
 import jax
 import pytest
 
@@ -42,11 +46,44 @@ def _half_batch(monkeypatch):
     monkeypatch.setattr(buffers.PrioritizedBuffer, "sample", halve_draw)
 
 
-FAULTS = {"unchanged": _unchanged, "half_batch": _half_batch}
+def _logp_wrong_std(monkeypatch):
+    from repro.models import mlp_policy
+
+    def sample_action(params, obs, key):
+        mean, std = mlp_policy.policy_dist(params, obs)
+        action = mean + std * jax.random.normal(key, mean.shape)
+        return action, mlp_policy.gaussian_logp(mean, std * 1.1, action)
+
+    monkeypatch.setattr(mlp_policy, "sample_action", sample_action)
 
 
-@pytest.mark.parametrize("fault", sorted(FAULTS))
-@pytest.mark.parametrize("name", ONE_CHIP)
+def _physics_term_dropped(monkeypatch):
+    from repro.kernels.env_step import ref
+    monkeypatch.setattr(ref, "CHEETAH_COUPLING", 0.0)
+
+
+def _gae_swapped(monkeypatch):
+    from repro.algos import gae
+    real = gae.gae
+
+    def swapped(rewards, values, dones, last_value, gamma=0.99, lam=0.95,
+                **kw):
+        return real(rewards, values, dones, last_value, lam, gamma, **kw)
+
+    monkeypatch.setattr(gae, "gae", swapped)
+
+
+FAULTS = {"unchanged": _unchanged, "half_batch": _half_batch,
+          "logp_wrong_std": _logp_wrong_std,
+          "physics_term_dropped": _physics_term_dropped,
+          "gae_swapped": _gae_swapped}
+CASES = [(name, fault) for name in ONE_CHIP
+         for fault in ("half_batch", "unchanged")] + [
+    ("ppo-sync-n10", fault)
+    for fault in ("gae_swapped", "logp_wrong_std", "physics_term_dropped")]
+
+
+@pytest.mark.parametrize("name,fault", CASES)
 def test_fault_makes_correct_false(name, fault, monkeypatch):
     FAULTS[fault](monkeypatch)
     out = run(tiny_cell(name))
